@@ -37,15 +37,19 @@ def _autotune_entry(order: int, smoke: bool, autotune_cache=None):
     user's tuned cache)."""
     from repro.kernels import autotune as at
 
-    entry = at.lookup(order=order, path=autotune_cache)
+    # this suite times the CPU: the kernels run in the Pallas interpreter
+    kind = at.detect_device_kind(interpret=True)
+    entry = at.lookup(kind, order=order, path=autotune_cache)
     if entry is None:
         # any-order entry for this device class: block-size winners are far
         # more stable across order than across device class
-        entry = at.lookup(path=autotune_cache)
+        entry = at.lookup(kind, path=autotune_cache)
     if entry is not None:
         return entry, "cache"
     entry = at.autotune(
         order=order,
+        device_kind=kind,
+        interpret=True,
         be_candidates=at.DEFAULT_BE_CANDIDATES[:2] if smoke else at.DEFAULT_BE_CANDIDATES,
         bf_candidates=at.DEFAULT_BF_CANDIDATES[:2] if smoke else at.DEFAULT_BF_CANDIDATES,
         reps=1 if smoke else 3,
@@ -88,7 +92,7 @@ def run(grid=(8, 8, 8), order=5, smoke=False, autotune_cache=None):
 
     entry, source = _autotune_entry(order, smoke, autotune_cache)
     be, bf = int(entry["be"]), int(entry["bf"])
-    interpret = bool(entry.get("interpret", jax.devices()[0].platform == "cpu"))
+    interpret = bool(entry["interpret"])
     K = s.mesh.K
     M = order + 1
     x, _ = lgl_nodes_weights(order)

@@ -75,7 +75,7 @@ def _sharded_row(result, order, n_steps, devices, reps):
     many devices (CI sets XLA_FLAGS=--xla_force_host_platform_device_count);
     emits a skip row otherwise."""
     from repro.dg.partitioned import PartitionedDG
-    from repro.jax_compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     n_avail = len(jax.devices())
     if n_avail < devices:

@@ -73,6 +73,9 @@ def main() -> None:
     if unknown:
         ap.error(f"unknown suites {unknown}; choose from {list(suites)}")
     picked = [s for s in (requested or list(suites)) if s not in args.skip]
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     print("name,us_per_call,derived")
     for name in picked:
         kwargs = {"smoke": args.smoke}

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.dg.partitioned import PartitionedDG
 from repro.dg.solver import gaussian_pulse, make_two_tree_solver
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -44,7 +45,7 @@ def main():
     jax.block_until_ready(qf)
     t_flat = time.perf_counter() - t0
 
-    mesh = jax.make_mesh((4,), ("data",))
+    mesh = make_mesh((4,), ("data",))
     pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
     qp0 = pdg.permute_in(q0)
     t0 = time.perf_counter()

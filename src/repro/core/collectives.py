@@ -23,12 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _axis_size(axis_name) -> int:
-    from repro.jax_compat import axis_size
-
-    return axis_size(axis_name)
-
-
 def _pad_to_multiple(x: jnp.ndarray, mult: int) -> Tuple[jnp.ndarray, int]:
     n = x.shape[0]
     pad = (-n) % mult
@@ -46,7 +40,7 @@ def hierarchical_psum(x: jnp.ndarray, fast_axis, slow_axis: Optional[str] = None
     """
     shape = x.shape
     flat = x.reshape(-1)
-    fsize = _axis_size(fast_axis)
+    fsize = lax.axis_size(fast_axis)
     flat, pad = _pad_to_multiple(flat, fsize)
     shard = lax.psum_scatter(flat, fast_axis, scatter_dimension=0, tiled=True)
     if slow_axis is not None:
@@ -98,7 +92,7 @@ def hierarchical_psum_compressed(
     """RS(fast) -> compressed psum(slow) -> AG(fast)."""
     shape = x.shape
     flat = x.reshape(-1)
-    fsize = _axis_size(fast_axis)
+    fsize = lax.axis_size(fast_axis)
     flat, pad = _pad_to_multiple(flat, fsize)
     shard = lax.psum_scatter(flat, fast_axis, scatter_dimension=0, tiled=True)
     if slow_axis is not None:

@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.jax_compat import axis_size as _axis_size
-
 
 def _perm_shift(axis_size: int, shift: int = 1):
     return [(i, (i + shift) % axis_size) for i in range(axis_size)]
@@ -77,7 +75,7 @@ def ring_allgather_matmul(
     Each ring step multiplies the chunk currently held (interior work) while
     the next chunk is in flight via ppermute (boundary exchange).
     """
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m_local, _ = x_shard.shape
     n = w.shape[1]
@@ -115,7 +113,7 @@ def matmul_ring_reducescatter(
     you are about to pass on (interior), then rotate the accumulator
     (boundary).  Requires m % P == 0.
     """
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = x.shape[0]
     if m % P:
@@ -153,7 +151,7 @@ def halo_exchange_1d(
     returns (recv_from_prev, recv_from_next).  With ``wrap=False`` the ends
     receive zeros (physical boundary).
     """
-    P = _axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     fwd = _perm_shift(P, 1) if wrap else [(i, i + 1) for i in range(P - 1)]
     bwd = _perm_shift(P, -1) if wrap else [(i + 1, i) for i in range(P - 1)]

@@ -41,11 +41,10 @@ OPPOSITE = (1, 0, 3, 2, 5, 4)
 def deriv(u: jnp.ndarray, D: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Apply the differentiation matrix along element axis (0,1,2) of
     u (K, F, M, M, M) — the paper's IIAX/IAIX/AIIX tensor applications."""
-    if axis == 0:
-        return jnp.einsum("am,kfmjl->kfajl", D, u)
-    if axis == 1:
-        return jnp.einsum("am,kfiml->kfial", D, u)
-    return jnp.einsum("am,kfijm->kfija", D, u)
+    spec = ("am,kfmjl->kfajl", "am,kfiml->kfial", "am,kfijm->kfija")[axis]
+    # full f32 products on the TPU MXU (its default is one bf16 pass, which
+    # costs the derivative three digits); the CPU computes f32 either way
+    return jnp.einsum(spec, D, u, precision=jax.lax.Precision.HIGHEST)
 
 
 def stress(q: jnp.ndarray, lam: jnp.ndarray, mu: jnp.ndarray) -> jnp.ndarray:
@@ -201,7 +200,7 @@ def surface_rhs(
                 axis=1,
             )
             FE, Fv = dg_flux_pallas(Sm, vm, Sp, vp, mats8, ax, sign,
-                                    interpret=(kernel_impl == "interpret"))
+                                    interpret=_interpret(kernel_impl))
         corr = jnp.concatenate([FE, Fv / rho[:, None, None, None]], axis=1)  # Q^-1 on v rows
         corr = -lift[ax] * corr
         corr = jnp.where(skip[:, None, None, None], 0.0, corr)
@@ -216,6 +215,17 @@ def surface_rhs(
     return out
 
 
+def _interpret(kernel_impl: str) -> bool:
+    """The Pallas ``interpret`` flag a non-xla ``kernel_impl`` names:
+    ``pallas`` is the Mosaic-compiled kernel (a TPU, or an error),
+    ``interpret`` the Pallas interpreter (the CPU test path)."""
+    if kernel_impl not in ("pallas", "interpret"):
+        raise ValueError(
+            f"kernel_impl must be 'xla', 'pallas' or 'interpret', got {kernel_impl!r}"
+        )
+    return kernel_impl == "interpret"
+
+
 def volume_rhs_impl(q, D, metrics, rho, lam, mu, kernel_impl: str = "xla"):
     """``volume_rhs`` behind the kernel switch: ``xla`` is the jnp reference,
     ``pallas``/``interpret`` run the paper's volume_loop as a TPU kernel."""
@@ -224,7 +234,7 @@ def volume_rhs_impl(q, D, metrics, rho, lam, mu, kernel_impl: str = "xla"):
     from repro.kernels.dg_volume import dg_volume_pallas
 
     return dg_volume_pallas(q, D, metrics, rho, lam, mu,
-                            interpret=(kernel_impl == "interpret"))
+                            interpret=_interpret(kernel_impl))
 
 
 def dg_rhs(q, D, metrics, lift, neighbors, rho, lam, mu, cp, cs, kernel_impl: str = "xla"):

@@ -264,9 +264,7 @@ class PartitionedDG:
     # ------------------------------------------------------------------
     def rhs(self, q_part: jnp.ndarray) -> jnp.ndarray:
         """Global-view rhs on the permuted state (sharded over the axis)."""
-        from repro.jax_compat import shard_map
-
-        f = shard_map(
+        f = jax.shard_map(
             self._rhs_local,
             mesh=self.mesh_axes,
             in_specs=(self.spec_q,) + self._operand_specs(),

@@ -8,10 +8,11 @@ tuning, and Tzovas & Predari's experimental study shows modeled costs must
 be re-fit from measurements.  This module closes both loops:
 
 1. **sweep** — time ``dg_volume_pallas`` over BE candidates and
-   ``dg_flux_pallas`` over BF candidates on the *current* device (real
-   TPU/GPU when present; interpret-mode fallback so CI exercises the full
-   machinery on CPU).  Each candidate is timed at two problem sizes and fit
-   as ``t(K) = overhead + K * sec_per_element``, so the winner is chosen on
+   ``dg_flux_pallas`` over BF candidates on the *current* device — compiled
+   on a TPU, or in the Pallas interpreter when the caller passes
+   ``interpret=True`` (how CI exercises the machinery on CPU).  Each
+   candidate is timed at two problem sizes and fit as
+   ``t(K) = overhead + K * sec_per_element``, so the winner is chosen on
    the marginal (roofline) cost and the intercept is a measured per-launch
    overhead;
 2. **cache** — winners land in a JSON keyed by
@@ -30,7 +31,7 @@ be re-fit from measurements.  This module closes both loops:
 CLI::
 
     PYTHONPATH=src python -m repro.kernels.autotune \
-        --device-class cpu-interpret --order 2 --smoke \
+        --device-class cpu-interpret --order 2 --smoke --interpret \
         --cache autotune_kernels.json
 
 Both kernels are arithmetically block-invariant (the volume kernel is
@@ -66,7 +67,7 @@ __all__ = [
 ]
 
 DEFAULT_BE_CANDIDATES = (8, 16, 32)
-DEFAULT_BF_CANDIDATES = (64, 128, 256)
+DEFAULT_BF_CANDIDATES = (128, 256, 512)  # faces are lanes: multiples of 128
 N_STAGES = 5  # LSRK4(5): rhs evaluations per timestep
 FACES_PER_ELEMENT = 6  # our surface_rhs computes all 6 faces of every element
 
@@ -83,17 +84,15 @@ def default_cache_path() -> str:
     return os.path.join(os.path.expanduser("~"), ".cache", "repro-dg", "autotune.json")
 
 
-def detect_device_kind(interpret: Optional[bool] = None) -> str:
+def detect_device_kind(interpret: bool) -> str:
     """A stable label for the current accelerator class (``tpu-v4``,
     ``nvidia-a100``, ``cpu``), suffixed ``-interpret`` when the Pallas
-    kernels would run in interpret mode (the CPU/CI fallback)."""
+    kernels run in interpret mode (the CPU/CI path)."""
     import jax
 
     d = jax.devices()[0]
     kind = str(getattr(d, "device_kind", "") or d.platform).lower()
     kind = kind.replace(" ", "-").replace("_", "-")
-    if interpret is None:
-        interpret = d.platform == "cpu"
     return f"{kind}-interpret" if interpret else kind
 
 
@@ -139,7 +138,7 @@ def lookup(
     if not cache:
         return None
     if device_kind is None:
-        device_kind = detect_device_kind()
+        device_kind = detect_device_kind(interpret=False)
     if order is not None:
         return cache.get(entry_key(device_kind, order, n_fields))
     for e in cache.values():
@@ -204,7 +203,8 @@ def sweep_volume(
     n_fields: int = 9,
     dtype: str = "float32",
     candidates: Sequence[int] = DEFAULT_BE_CANDIDATES,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
     reps: int = 3,
     size_factor: int = 8,
     seed: int = 0,
@@ -216,8 +216,6 @@ def sweep_volume(
     from repro.dg.basis import diff_matrix, lgl_nodes_weights
     from repro.kernels.dg_volume import dg_volume_pallas
 
-    if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
     M = order + 1
     x, _ = lgl_nodes_weights(order)
     D = jnp.asarray(diff_matrix(x), dtype)
@@ -249,7 +247,8 @@ def sweep_flux(
     order: int,
     dtype: str = "float32",
     candidates: Sequence[int] = DEFAULT_BF_CANDIDATES,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
     reps: int = 3,
     size_factor: int = 8,
     seed: int = 0,
@@ -260,8 +259,6 @@ def sweep_flux(
 
     from repro.kernels.dg_flux import dg_flux_pallas
 
-    if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
     M = order + 1
     rng = np.random.default_rng(seed)
     out: Dict[str, dict] = {}
@@ -299,7 +296,8 @@ def autotune(
     device_kind: Optional[str] = None,
     be_candidates: Sequence[int] = DEFAULT_BE_CANDIDATES,
     bf_candidates: Sequence[int] = DEFAULT_BF_CANDIDATES,
-    interpret: Optional[bool] = None,
+    *,
+    interpret: bool,
     reps: int = 3,
     size_factor: int = 8,
     cache_path: Optional[str] = None,
@@ -312,14 +310,12 @@ def autotune(
     marginal per-evaluation cost times the 5 LSRK stages; int_flux times the
     6 faces our surface pass computes per element) — directly consumable by
     ``CalibrationTable.from_autotune``."""
-    import jax
-
-    if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
     if device_kind is None:
         device_kind = detect_device_kind(interpret)
-    vol = sweep_volume(order, n_fields, dtype, be_candidates, interpret, reps, size_factor)
-    flx = sweep_flux(order, dtype, bf_candidates, interpret, reps, size_factor)
+    vol = sweep_volume(order, n_fields, dtype, be_candidates,
+                       interpret=interpret, reps=reps, size_factor=size_factor)
+    flx = sweep_flux(order, dtype, bf_candidates,
+                     interpret=interpret, reps=reps, size_factor=size_factor)
     be = _winner(vol, "sec_per_element")
     bf = _winner(flx, "sec_per_face")
     entry = {
@@ -371,13 +367,13 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--cache", default=None,
                     help=f"cache JSON path (default: {default_cache_path()})")
-    ap.add_argument("--interpret", choices=["auto", "on", "off"], default="auto",
-                    help="force interpret mode on/off (auto: on iff CPU backend)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the kernels in the Pallas interpreter (the CPU "
+                         "path); by default they are compiled with Mosaic")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sweep (2 candidates, 1 rep, small sizes) — CI-safe")
     args = ap.parse_args(argv)
 
-    interpret = {"auto": None, "on": True, "off": False}[args.interpret]
     be_cands = args.be or (DEFAULT_BE_CANDIDATES[:2] if args.smoke else DEFAULT_BE_CANDIDATES)
     bf_cands = args.bf or (DEFAULT_BF_CANDIDATES[:2] if args.smoke else DEFAULT_BF_CANDIDATES)
     entry = autotune(
@@ -387,7 +383,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
         device_kind=args.device_class,
         be_candidates=be_cands,
         bf_candidates=bf_cands,
-        interpret=interpret,
+        interpret=args.interpret,
         reps=1 if args.smoke else args.reps,
         size_factor=4 if args.smoke else 8,
         cache_path=args.cache,

@@ -1,16 +1,21 @@
 """Pallas TPU kernel for the paper's ``int_flux`` / ``godonov_flux`` hot-spot.
 
 The exact Riemann correction is embarrassingly parallel over face nodes
-(paper section 4) — pure VPU work.  The TPU layout flattens each face's
-(M x M) nodes into lanes and blocks BF faces into sublanes, so one grid
-step processes a (BF, M*M) tile per field with the 8 material scalars held
-alongside.  Axis/sign are compile-time grid parameters (one kernel
-instantiation per face direction, as in the solver's face loop).
+(paper section 4) — pure VPU work.  The TPU layout puts faces on lanes: the
+wrapper transposes each ``(F, C, M, M)`` trace array to ``(C, M*M, F)``, so
+one grid step holds a ``(C, M*M, BF)`` block — the field index is a leading
+(untiled) axis, each field a lane-dense ``(M*M, BF)`` tile — and the 8
+material scalars ride along as an ``(8, BF)`` block broadcast over
+sublanes.  The strain/velocity corrections are assembled by stacking their
+components, never by scatter.  Axis/sign are compile-time parameters (one
+kernel instantiation per face direction, as in the solver's face loop).
 
-VMEM per step: (BF=128 faces) x (2x9 fields + out) x 64 lanes x 4 B ~= 0.9 MiB.
+VMEM per step at order 7, BF = 128, f32: (2 x 9 inputs + 9 outputs) x 64 x
+128 x 4 B = 0.9 MiB, x2 buffers.
 
 Validated against ``ref.dg_flux_ref`` in interpret mode across orders,
-dtypes, and acoustic/elastic/coupled material draws.
+dtypes, and acoustic/elastic/coupled material draws;
+``tests/test_tpu_compile.py`` compiles it for a v5e.
 
 Reached from the solver via the ``kernel_impl`` switch
 (``dg.operators.surface_rhs(kernel_impl="pallas"|"interpret")``): one
@@ -21,7 +26,8 @@ fused step pipeline (``runtime.pipeline``) alike.
 BF = 128 is the hand-derived default; ``repro.kernels.autotune`` sweeps it
 per device class and installs the measured winner via ``set_block_faces``
 (or per call via ``dg_flux_pallas(..., bf=...)``).  The kernel is pure
-per-face VPU work, so results are bitwise-invariant in BF.
+per-face VPU work, so results are bitwise-invariant in BF.  Faces are the
+lane axis, so on a TPU BF must be a multiple of 128.
 """
 
 from __future__ import annotations
@@ -31,10 +37,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-BF = 128  # default faces per grid step
+BF = 128  # default faces per grid step (one lane width)
 
 # autotuned override (repro.kernels.autotune.activate): None = use BF.
 # Baked into programs at trace time — activate BEFORE building pipelines.
@@ -57,15 +62,15 @@ SYM = ((0, 5, 4), (5, 1, 3), (4, 3, 2))
 
 
 def _flux_kernel(Sm_ref, vm_ref, Sp_ref, vp_ref, mat_ref, FE_ref, Fv_ref, *, axis: int, sign: float):
+    """S*_ref: (6, MM, BF); v*_ref: (3, MM, BF); mat_ref: (8, BF);
+    FE_ref: (6, MM, BF); Fv_ref: (3, MM, BF)."""
     # compute in >= f32 (bf16 inputs upcast; f64 kept when x64 is on)
     cdt = jnp.result_type(Sm_ref.dtype, jnp.float32)
-    Sm = Sm_ref[...].astype(cdt)  # (BF, 6, MM)
-    vm = vm_ref[...].astype(cdt)  # (BF, 3, MM)
-    Sp = Sp_ref[...].astype(cdt)
-    vp = vp_ref[...].astype(cdt)
-    mat = mat_ref[...].astype(cdt)  # (BF, 8)
+    S_j = lambda c: Sm_ref[c].astype(cdt) - Sp_ref[c].astype(cdt)  # (MM, BF)
+    v_j = lambda c: vm_ref[c].astype(cdt) - vp_ref[c].astype(cdt)
+    mat = mat_ref[...].astype(cdt)  # (8, BF)
 
-    e = lambda c: mat[:, c][:, None]
+    e = lambda c: mat[c:c + 1]  # (1, BF), broadcast over the face nodes
     rcp_m, rcs_m = e(0) * e(1), e(0) * e(2)
     rcp_p, rcs_p = e(4) * e(5), e(4) * e(6)
     mu_m = e(3)
@@ -73,26 +78,26 @@ def _flux_kernel(Sm_ref, vm_ref, Sp_ref, vp_ref, mat_ref, FE_ref, Fv_ref, *, axi
     denom = rcs_m + rcs_p
     k1 = jnp.where(mu_m > 0, 1.0 / jnp.maximum(denom, 1e-30), 0.0)
 
-    S_j = Sm - Sp
-    v_j = vm - vp
     a0, a1, a2 = axis, (axis + 1) % 3, (axis + 2) % 3
-    S_aa = S_j[:, SYM[a0][a0]]
-    S_a1 = S_j[:, SYM[a0][a1]]
-    S_a2 = S_j[:, SYM[a0][a2]]
+    S_aa = S_j(SYM[a0][a0])
+    S_a1 = S_j(SYM[a0][a1])
+    S_a2 = S_j(SYM[a0][a2])
+    v0, v1, v2 = v_j(a0), v_j(a1), v_j(a2)
 
-    a = k0 * (S_aa + rcp_p * sign * v_j[:, a0])
-    FE = jnp.zeros_like(S_j)
-    FE = FE.at[:, SYM[a0][a0]].set(a)
-    FE = FE.at[:, SYM[a0][a1]].set(0.5 * k1 * (S_a1 + rcs_p * sign * v_j[:, a1]))
-    FE = FE.at[:, SYM[a0][a2]].set(0.5 * k1 * (S_a2 + rcs_p * sign * v_j[:, a2]))
+    a = k0 * (S_aa + rcp_p * sign * v0)
+    zero = jnp.zeros_like(a)
+    FE = [zero] * 6
+    FE[SYM[a0][a0]] = a
+    FE[SYM[a0][a1]] = 0.5 * k1 * (S_a1 + rcs_p * sign * v1)
+    FE[SYM[a0][a2]] = 0.5 * k1 * (S_a2 + rcs_p * sign * v2)
 
-    Fv = jnp.zeros_like(v_j)
-    Fv = Fv.at[:, a0].set(a * rcp_m * sign)
-    Fv = Fv.at[:, a1].set(k1 * rcs_m * (sign * S_a1 + rcs_p * v_j[:, a1]))
-    Fv = Fv.at[:, a2].set(k1 * rcs_m * (sign * S_a2 + rcs_p * v_j[:, a2]))
+    Fv = [zero] * 3
+    Fv[a0] = a * rcp_m * sign
+    Fv[a1] = k1 * rcs_m * (sign * S_a1 + rcs_p * v1)
+    Fv[a2] = k1 * rcs_m * (sign * S_a2 + rcs_p * v2)
 
-    FE_ref[...] = FE.astype(FE_ref.dtype)
-    Fv_ref[...] = Fv.astype(Fv_ref.dtype)
+    FE_ref[...] = jnp.stack(FE).astype(FE_ref.dtype)
+    Fv_ref[...] = jnp.stack(Fv).astype(Fv_ref.dtype)
 
 
 def dg_flux_pallas(
@@ -104,42 +109,41 @@ def dg_flux_pallas(
     axis: int,
     sign: float,
     *,
-    interpret: bool = True,
+    interpret: bool,
     bf: Optional[int] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Riemann corrections ``(FE (F, 6, M, M), Fv (F, 3, M, M))``.
+    ``interpret=False`` compiles the kernel with Mosaic (TPU only);
+    ``interpret=True`` runs it in the Pallas interpreter (the CPU test
+    path).  There is no default."""
     BF = block_faces() if bf is None else int(bf)
     F, _, M, _ = Sm.shape
     MM = M * M
     pad = (-F) % BF
-    def p(x, fill=0.0):
-        if pad:
-            return jnp.concatenate([x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
-        return x
-    Smf = p(Sm).reshape(F + pad, 6, MM)
-    vmf = p(vm).reshape(F + pad, 3, MM)
-    Spf = p(Sp).reshape(F + pad, 6, MM)
-    vpf = p(vp).reshape(F + pad, 3, MM)
-    matf = p(mats, fill=1.0)
     Fp = F + pad
 
+    def faces_last(x, fill=0.0):
+        """(F, C, ...) -> (C, prod(...), Fp): faces padded onto lanes."""
+        if pad:
+            x = jnp.concatenate([x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
+        return x.reshape(Fp, x.shape[1], -1).transpose(1, 2, 0)
+
+    spec = lambda C: pl.BlockSpec((C, MM, BF), lambda i: (0, 0, i))
     FE, Fv = pl.pallas_call(
         functools.partial(_flux_kernel, axis=axis, sign=float(sign)),
         grid=(Fp // BF,),
-        in_specs=[
-            pl.BlockSpec((BF, 6, MM), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BF, 3, MM), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BF, 6, MM), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BF, 3, MM), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BF, 8), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((BF, 6, MM), lambda i: (i, 0, 0)),
-            pl.BlockSpec((BF, 3, MM), lambda i: (i, 0, 0)),
-        ],
+        in_specs=[spec(6), spec(3), spec(6), spec(3), pl.BlockSpec((8, BF), lambda i: (0, i))],
+        out_specs=[spec(6), spec(3)],
         out_shape=[
-            jax.ShapeDtypeStruct((Fp, 6, MM), Sm.dtype),
-            jax.ShapeDtypeStruct((Fp, 3, MM), Sm.dtype),
+            jax.ShapeDtypeStruct((6, MM, Fp), Sm.dtype),
+            jax.ShapeDtypeStruct((3, MM, Fp), Sm.dtype),
         ],
         interpret=interpret,
-    )(Smf, vmf, Spf, vpf, matf)
-    return FE[:F].reshape(F, 6, M, M), Fv[:F].reshape(F, 3, M, M)
+        name="dg_flux",
+    )(faces_last(Sm), faces_last(vm), faces_last(Sp), faces_last(vp),
+      faces_last(mats[:, :, None], fill=1.0)[:, 0])
+
+    def faces_first(x):
+        return x.transpose(2, 0, 1)[:F].reshape(F, x.shape[0], M, M)
+
+    return faces_first(FE), faces_first(Fv)

@@ -2,25 +2,31 @@
 
 The paper hand-vectorizes the elemental tensor-product applications
 (IIAX/IAIX/AIIX) with AVX/MIC intrinsics.  The TPU adaptation rethinks the
-layout for the MXU instead of porting the vector code:
+layout for Mosaic instead of porting the vector code:
 
-  * an (M x M) derivative matrix (M = order+1 = 8) used alone occupies
-    8/128 of the MXU's contraction dim (~6% utilization);
-  * we therefore process BE = 16 elements per grid step and apply the
-    BLOCK-DIAGONAL operator D16 = kron(I_16, D) (128 x 128) — the r1
-    derivative of 16 elements becomes ONE full-width MXU pass, with the
-    9 fields x M^2 = 576 trailing lanes amortizing weight loads;
-  * the r2/r3 derivatives contract the right factor (X @ D16^T) with the
-    same blocking after an in-VMEM transpose;
-  * flux assembly (stress, sym-grad combinations, 1/rho scaling) is fused
-    into the same kernel (VPU elementwise) so the block's rhs leaves VMEM
-    exactly once.
+  * each element's ``M^3`` nodes (512 at order 7) are the lane axis, so the
+    field array is viewed as ``(K, 9 * M^3)`` — a free reshape of the
+    ``(K, 9, M, M, M)`` state — and one grid step holds a ``(BE, 9 * M^3)``
+    block: BE elements on sublanes, field ``f`` at the lane-aligned slice
+    ``[f * M^3, (f + 1) * M^3)``;
+  * the derivative along element axis ``a`` is then ONE 2-D dot with a
+    precomputed ``(M^3, M^3)`` operator, ``kron(D, I, I)^T`` and its two
+    permutations (the metric ``2/h`` folded in) — no transposes or reshapes
+    across tiled dimensions inside the kernel.  The six fields each axis
+    needs (three velocities, one stress row) are stacked on sublanes so
+    each axis is a single ``(6 * BE, M^3) x (M^3, M^3)`` MXU pass;
+  * stress, the sym-grad combinations and the 1/rho scaling run on the VPU
+    in the same kernel, so the block's rhs leaves VMEM exactly once.
 
-VMEM footprint per grid step: q block (16, 9, 512) f32 = 288 KiB + two
-derivative temporaries of the same size + D16 (64 KiB) ~= 0.9 MiB << 16 MiB.
+The dense operator does ``M^3 / M`` times the multiply-adds of the
+tensor-product form; which layout is fastest on the chip is not measured.
+
+VMEM per grid step at order 7, BE = 16, f32: the operators 3 MiB (x2
+buffers), q and out blocks 288 KiB each (x2 buffers), the stacked dot
+operands ~0.6 MiB — ~8.5 MiB, under the 16 MiB default scoped limit.
 
 Validated against ``ref.dg_volume_ref`` in interpret mode (CPU) across
-orders/dtypes; the TPU (Mosaic) path is the deployment target.
+orders/dtypes; ``tests/test_tpu_compile.py`` compiles it for a v5e.
 
 BE = 16 is the hand-derived default; ``repro.kernels.autotune`` sweeps it
 per device class and installs the measured winner via ``set_block_elems``
@@ -35,14 +41,17 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-BE = 16  # default elements per grid step -> 16*M = 128 MXU rows at M=8
+BE = 16  # default elements per grid step (a multiple of the 8 sublanes)
 
 # autotuned override (repro.kernels.autotune.activate): None = use BE.
 # Baked into programs at trace time — activate BEFORE building pipelines.
 _ACTIVE_BE: Optional[int] = None
+
+# per axis a, the stress components whose a-derivative enters div S rows
+# x, y, z: SYM[r][a] for r = 0, 1, 2
+_DIV_S = ((0, 5, 4), (5, 1, 3), (4, 3, 2))
 
 
 def set_block_elems(be: Optional[int]) -> None:
@@ -57,73 +66,50 @@ def block_elems() -> int:
     return BE if _ACTIVE_BE is None else _ACTIVE_BE
 
 
-def _volume_kernel(q_ref, d16_ref, mat_ref, out_ref, *, M: int, metrics, BE: int):
-    """q_ref: (BE, 9, M, M, M); d16_ref: (BE*M, BE*M); mat_ref: (BE, 3) =
-    (rho, lam, mu); out_ref: (BE, 9, M, M, M)."""
+def derivative_operators(D: jnp.ndarray, metrics: Tuple[float, float, float]) -> jnp.ndarray:
+    """(3, M^3, M^3): right-multiplying a row of element nodes by entry
+    ``a`` differentiates along element axis ``a`` and scales by
+    ``metrics[a]``.  Built with jnp, so a traced ``D`` works too."""
+    M = D.shape[0]
+    eye = jnp.eye(M, dtype=D.dtype)
+    kron3 = lambda a, b, c: jnp.kron(jnp.kron(a, b), c)
+    ops = (kron3(D, eye, eye), kron3(eye, D, eye), kron3(eye, eye, D))
+    return jnp.stack([op.T * m for op, m in zip(ops, metrics)])
+
+
+def _volume_kernel(q_ref, ops_ref, mat_ref, out_ref, *, N: int, BE: int):
+    """q_ref/out_ref: (BE, 9 * N) with N = M^3 nodes per field on lanes;
+    ops_ref: (3, N, N); mat_ref: (BE, 3) = (rho, lam, mu)."""
     cdt = jnp.result_type(q_ref.dtype, jnp.float32)
-    q = q_ref[...].astype(cdt)
-    D16 = d16_ref[...].astype(cdt)
-    rho = mat_ref[:, 0][:, None, None, None]
-    lam = mat_ref[:, 1][:, None, None, None]
-    mu = mat_ref[:, 2][:, None, None, None]
+    field = lambda f: q_ref[:, f * N:(f + 1) * N].astype(cdt)  # (BE, N)
+    mat = mat_ref[...].astype(cdt)
+    rho, lam, mu = mat[:, 0:1], mat[:, 1:2], mat[:, 2:3]
 
-    v = q[:, 6:9]  # (BE, 3, M, M, M)
-    tr = q[:, 0] + q[:, 1] + q[:, 2]
-    S = jnp.stack(
-        [
-            lam * tr + 2 * mu * q[:, 0],
-            lam * tr + 2 * mu * q[:, 1],
-            lam * tr + 2 * mu * q[:, 2],
-            2 * mu * q[:, 3],
-            2 * mu * q[:, 4],
-            2 * mu * q[:, 5],
-        ],
-        axis=1,
-    )  # (BE, 6, M, M, M)
+    E = [field(f) for f in range(6)]
+    v = [field(6 + f) for f in range(3)]
+    tr = E[0] + E[1] + E[2]
+    S = [lam * tr + 2 * mu * E[c] for c in range(3)] + [2 * mu * E[c] for c in range(3, 6)]
 
-    def dax(u, axis):
-        """Derivative along element axis via the block-diagonal D16.
-        u: (BE, F, M, M, M)."""
-        F = u.shape[1]
-        if axis == 0:
-            # rows: (BE*M); lanes: F*M^2 — one full-width MXU pass
-            x = u.transpose(0, 2, 1, 3, 4).reshape(BE * M, F * M * M)
-            y = jax.lax.dot_general(D16, x, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=cdt)
-            return y.reshape(BE, M, F, M, M).transpose(0, 2, 1, 3, 4) * metrics[0]
-        if axis == 1:
-            x = u.transpose(0, 3, 1, 2, 4).reshape(BE * M, F * M * M)
-            y = jax.lax.dot_general(D16, x, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=cdt)
-            return y.reshape(BE, M, F, M, M).transpose(0, 2, 3, 1, 4) * metrics[1]
-        x = u.transpose(0, 4, 1, 2, 3).reshape(BE * M, F * M * M)
-        y = jax.lax.dot_general(D16, x, (((1,), (0,)), ((), ())),
+    def d(a):
+        """a-derivatives of (v0, v1, v2, the three stress components of
+        div S along a), as one MXU pass over the sublane-stacked fields."""
+        x = jnp.concatenate(v + [S[c] for c in _DIV_S[a]], axis=0)  # (6 BE, N)
+        y = jax.lax.dot_general(x, ops_ref[a].astype(cdt), (((1,), (0,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=cdt)
-        return y.reshape(BE, M, F, M, M).transpose(0, 2, 3, 4, 1) * metrics[2]
+        return [y[i * BE:(i + 1) * BE] for i in range(6)]
 
-    dv0 = dax(v, 0)
-    dv1 = dax(v, 1)
-    dv2 = dax(v, 2)
-    dS0 = dax(S, 0)
-    dS1 = dax(S, 1)
-    dS2 = dax(S, 2)
-
-    # SYM index: (a,b) -> 6-component slot
-    out = jnp.stack(
-        [
-            dv0[:, 0],
-            dv1[:, 1],
-            dv2[:, 2],
-            0.5 * (dv2[:, 1] + dv1[:, 2]),
-            0.5 * (dv2[:, 0] + dv0[:, 2]),
-            0.5 * (dv1[:, 0] + dv0[:, 1]),
-            (dS0[:, 0] + dS1[:, 5] + dS2[:, 4]) / rho,
-            (dS0[:, 5] + dS1[:, 1] + dS2[:, 3]) / rho,
-            (dS0[:, 4] + dS1[:, 3] + dS2[:, 2]) / rho,
-        ],
-        axis=1,
-    )
-    out_ref[...] = out.astype(out_ref.dtype)
+    d0, d1, d2 = d(0), d(1), d(2)
+    out = [
+        d0[0],
+        d1[1],
+        d2[2],
+        0.5 * (d2[1] + d1[2]),
+        0.5 * (d2[0] + d0[2]),
+        0.5 * (d1[0] + d0[1]),
+    ] + [(d0[3 + r] + d1[3 + r] + d2[3 + r]) / rho for r in range(3)]
+    for f, o in enumerate(out):
+        out_ref[:, f * N:(f + 1) * N] = o.astype(out_ref.dtype)
 
 
 def dg_volume_pallas(
@@ -134,33 +120,37 @@ def dg_volume_pallas(
     lam: jnp.ndarray,
     mu: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool,
     be: Optional[int] = None,
 ) -> jnp.ndarray:
+    """Volume rhs (K, 9, M, M, M).  ``interpret=False`` compiles the kernel
+    with Mosaic (TPU only); ``interpret=True`` runs it in the Pallas
+    interpreter (the CPU test path).  There is no default."""
     BE = block_elems() if be is None else int(be)
     K, F, M = q.shape[0], q.shape[1], q.shape[2]
-    if K % BE:
-        pad = BE - K % BE
+    N = M * M * M
+    pad = (-K) % BE
+    if pad:
         q = jnp.concatenate([q, jnp.zeros((pad,) + q.shape[1:], q.dtype)])
         rho = jnp.concatenate([rho, jnp.ones(pad, rho.dtype)])
         lam = jnp.concatenate([lam, jnp.ones(pad, lam.dtype)])
         mu = jnp.concatenate([mu, jnp.ones(pad, mu.dtype)])
-    else:
-        pad = 0
-    Kp = q.shape[0]
-    d16 = jnp.asarray(np.kron(np.eye(BE), np.asarray(D, np.float64)), q.dtype)
+    Kp = K + pad
+    ops = derivative_operators(jnp.asarray(D, q.dtype), tuple(float(m) for m in metrics))
     mats = jnp.stack([rho, lam, mu], axis=1).astype(q.dtype)
 
     out = pl.pallas_call(
-        functools.partial(_volume_kernel, M=M, metrics=tuple(float(m) for m in metrics), BE=BE),
+        functools.partial(_volume_kernel, N=N, BE=BE),
         grid=(Kp // BE,),
         in_specs=[
-            pl.BlockSpec((BE, F, M, M, M), lambda i: (i, 0, 0, 0, 0)),
-            pl.BlockSpec((BE * M, BE * M), lambda i: (0, 0)),
+            pl.BlockSpec((BE, F * N), lambda i: (i, 0)),
+            pl.BlockSpec((3, N, N), lambda i: (0, 0, 0)),
             pl.BlockSpec((BE, 3), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((BE, F, M, M, M), lambda i: (i, 0, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Kp, F, M, M, M), q.dtype),
+        out_specs=pl.BlockSpec((BE, F * N), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Kp, F * N), q.dtype),
         interpret=interpret,
-    )(q, d16, mats)
+        name="dg_volume",
+    )(q.reshape(Kp, F * N), ops, mats)
+    out = out.reshape(Kp, F, M, M, M)
     return out[:K] if pad else out

@@ -98,8 +98,11 @@ def flash_attention_pallas(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
+    """``interpret=False`` compiles the kernel with Mosaic (TPU only);
+    ``interpret=True`` runs it in the Pallas interpreter (the CPU test
+    path).  There is no default."""
     B, H, Sq, D = q.shape
     Skv = k.shape[2]
     scale = scale or 1.0 / math.sqrt(D)

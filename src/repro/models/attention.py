@@ -361,9 +361,7 @@ def ring_attention(
     B, Hq, S_loc, D = q.shape
     Hkv = k.shape[1]
     g = Hq // Hkv
-    from repro.jax_compat import axis_size
-
-    P = axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     scale = scale or 1.0 / _math.sqrt(D)
     qg = q.reshape(B, Hkv, g, S_loc, D)

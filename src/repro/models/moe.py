@@ -198,9 +198,7 @@ def moe_apply_ep(
     T_loc, d = x.shape
     E = cfg.n_experts
     k = cfg.experts_per_token
-    from repro.jax_compat import axis_size
-
-    dp = axis_size(data_axis)
+    dp = lax.axis_size(data_axis)
     E_loc = params["w_gate"].shape[0]
     E_pad = E_loc * dp
     rep = E_pad // E
@@ -289,9 +287,7 @@ def moe_ep_sharded(
         met = {k: lax.pmean(v, bspec + ("model",)) for k, v in met.items()}
         return y.reshape(hl.shape), met
 
-    from repro.jax_compat import shard_map
-
-    f = shard_map(
+    f = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(pspecs, P(bspec, None, None)),

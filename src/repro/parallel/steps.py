@@ -196,9 +196,7 @@ def make_train_step(
         pod = lambda tree: jax.tree.map(
             lambda s: _pod_only(s.spec), tree, is_leaf=lambda x: isinstance(x, NamedSharding)
         )
-        from repro.jax_compat import shard_map
-
-        f = shard_map(
+        f = jax.shard_map(
             podwise_step,
             mesh=sh.mesh,
             in_specs=(pod(sh.params), pod(sh.opt), pod(sh.batch)),

@@ -603,12 +603,12 @@ class ShardedStepPipeline:
         return rhs
 
     def _shard(self, f, n_carry_out: int):
-        from repro.jax_compat import shard_map
+        import jax
 
         p = self.pdg
         qs = p.spec_q
         out = qs if n_carry_out == 1 else (qs,) * n_carry_out
-        return shard_map(
+        return jax.shard_map(
             f,
             mesh=p.mesh_axes,
             in_specs=(qs,) * n_carry_out
@@ -678,7 +678,6 @@ class ShardedStepPipeline:
             from jax.sharding import PartitionSpec
 
             from repro.dg.rk import lsrk45_step
-            from repro.jax_compat import shard_map
 
             p = self.pdg
             local_rhs = self._local_rhs()
@@ -712,7 +711,7 @@ class ShardedStepPipeline:
             qs = p.spec_q
             scalar = PartitionSpec()
             vec = PartitionSpec(p.axis)
-            f = shard_map(
+            f = jax.shard_map(
                 local_run,
                 mesh=p.mesh_axes,
                 in_specs=(qs, qs, vec, scalar, scalar, vec) + p._operand_specs(),

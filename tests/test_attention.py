@@ -92,7 +92,8 @@ import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.models.attention import naive_attention, ring_attention
-from repro.jax_compat import make_mesh, shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("data",))
 ks = jax.random.split(jax.random.PRNGKey(0), 3)
 q = jax.random.normal(ks[0], (2, 4, 256, 32), jnp.float32)

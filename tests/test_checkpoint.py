@@ -55,12 +55,13 @@ def test_elastic_reshard(tmp_path, subproc):
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save, restore
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 x = jnp.arange(64.0).reshape(8, 8)
 xs = jax.device_put(x, NamedSharding(mesh, P("data", "model")))
 save(r"{tmp_path}", 1, {{"w": xs}})
 # restore to a different mesh
-mesh2 = jax.make_mesh((4,), ("data",))
+mesh2 = make_mesh((4,), ("data",))
 sh2 = {{"w": NamedSharding(mesh2, P("data", None))}}
 t2, _ = restore(r"{tmp_path}", {{"w": x}}, shardings=sh2)
 np.testing.assert_array_equal(np.asarray(t2["w"]), np.asarray(x))

@@ -7,7 +7,8 @@ def test_overlap_primitives(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from functools import partial
-from repro.jax_compat import make_mesh, shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.overlap import ring_allgather_matmul, matmul_ring_reducescatter, halo_exchange_1d
 mesh = make_mesh((4,), ("x",))
 key = jax.random.PRNGKey(0)
@@ -32,7 +33,8 @@ def test_hierarchical_and_compressed_psum(subproc):
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.jax_compat import make_mesh, shard_map
+from jax import shard_map
+from repro.launch.mesh import make_mesh
 from repro.core.collectives import hierarchical_psum, hierarchical_psum_compressed
 mesh2 = make_mesh((2, 2), ("s", "f"))
 y = jax.random.normal(jax.random.PRNGKey(0), (4, 6, 5), jnp.float32)
@@ -58,7 +60,7 @@ def test_moe_ep_sharded_matches_gspmd(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from repro.models.common import ModelConfig
 from repro.models.moe import moe_init, moe_apply, moe_ep_sharded
-from repro.jax_compat import make_mesh
+from repro.launch.mesh import make_mesh
 cfg = ModelConfig(arch_id="m", family="moe", n_layers=1, d_model=16, n_heads=2,
                   n_kv_heads=2, d_ff=32, vocab_size=64, n_experts=4,
                   experts_per_token=2, capacity_factor=8.0)

@@ -87,7 +87,8 @@ jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
 from repro.dg.solver import make_two_tree_solver, gaussian_pulse
 from repro.dg.partitioned import PartitionedDG
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 s = make_two_tree_solver(grid=(8,4,4), order=3, extent=(2.,1.,1.))
 pdg = PartitionedDG(solver=s, mesh_axes=mesh)
 rng = np.random.default_rng(0)

@@ -21,7 +21,7 @@ from repro.optim import OptConfig, init_opt_state
 from repro.parallel.steps import make_shardings, make_train_step
 from repro.runtime import FailureInjector, TrainSupervisor
 from repro.runtime.elastic import rescale_plan
-from repro.jax_compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 cfg = smoke_config(get_config("qwen2-7b")).replace(tp_size=2)
 lm = LM(cfg)

@@ -399,7 +399,8 @@ from repro.dg.solver import gaussian_pulse, make_two_tree_solver
 
 solver = make_two_tree_solver(grid=(8, 4, 4), order=3, extent=(2.0, 1.0, 1.0))
 q0 = gaussian_pulse(solver, center=(0.5, 0.5, 0.5))
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
 ex = pdg.bind_executor(pdg.make_executor(rebalance_every=2))
 qp = pdg.run(pdg.permute_in(q0), 4, observe=True)

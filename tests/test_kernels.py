@@ -19,7 +19,7 @@ def _tol(dt):
     return dict(rtol=5e-4, atol=5e-4) if dt == "float32" else dict(rtol=1e-11, atol=1e-11)
 
 
-@pytest.mark.parametrize("K,order", [(16, 7), (24, 3), (7, 5), (1, 2)])
+@pytest.mark.parametrize("K,order", [(16, 7), (37, 7), (24, 3), (7, 5), (1, 2)])
 @pytest.mark.parametrize("dt", ["float32", "float64"])
 def test_dg_volume_kernel(K, order, dt):
     M = order + 1
@@ -35,7 +35,7 @@ def test_dg_volume_kernel(K, order, dt):
     np.testing.assert_allclose(out, want, **_tol(dt))
 
 
-@pytest.mark.parametrize("F,M", [(10, 8), (200, 4), (128, 8)])
+@pytest.mark.parametrize("F,M", [(10, 8), (200, 4), (128, 8), (300, 8)])
 @pytest.mark.parametrize("dt", ["float32", "float64"])
 @pytest.mark.parametrize("axis,sign", [(0, 1.0), (1, -1.0), (2, 1.0)])
 def test_dg_flux_kernel(F, M, dt, axis, sign):

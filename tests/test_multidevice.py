@@ -30,6 +30,7 @@ import numpy as np, jax.numpy as jnp
 from repro.dg.mesh import make_brick
 from repro.dg.solver import DGSolver
 from repro.dg.partitioned import PartitionedDG
+from repro.launch.mesh import make_mesh
 from repro.runtime.executor import BlockedDGEngine, NestedPartitionExecutor
 
 def periodic_solver(grid, impl, order=2, lam=1.0, mu=0.0):
@@ -59,7 +60,7 @@ for impl in ("xla", "interpret"):
         rng = np.random.default_rng(7)
         q0 = jnp.asarray(rng.standard_normal((K, 9, solver.M, solver.M, solver.M)))
         dt = solver.cfl_dt()
-        mesh = jax.make_mesh((slabs,), ("data",))
+        mesh = make_mesh((slabs,), ("data",))
         pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
         pipe = pdg.pipeline()
         qp = pdg.permute_in(q0)
@@ -99,7 +100,7 @@ solver = periodic_solver((4, 2, 2), "xla", lam=1.1, mu=0.3)
 K = solver.mesh.K
 rng = np.random.default_rng(7)
 q0 = jnp.asarray(rng.standard_normal((K, 9, solver.M, solver.M, solver.M)))
-mesh = jax.make_mesh((2,), ("data",))
+mesh = make_mesh((2,), ("data",))
 pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
 qp = pdg.permute_in(q0)
 dt = solver.cfl_dt()
@@ -118,6 +119,7 @@ import numpy as np, jax.numpy as jnp
 from repro.dg.mesh import make_brick
 from repro.dg.solver import DGSolver
 from repro.dg.partitioned import PartitionedDG
+from repro.launch.mesh import make_mesh
 
 mesh_b = make_brick((4, 2, 2), (1.0, 1.0, 0.5), periodic=True)
 K = mesh_b.K
@@ -128,7 +130,7 @@ q0 = jnp.asarray(rng.standard_normal((K, 9, 3, 3, 3)))
 dt = solver.cfl_dt()
 
 for slabs in (2, 4):
-    mesh = jax.make_mesh((slabs,), ("data",))
+    mesh = make_mesh((slabs,), ("data",))
     pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
     pipe = pdg.pipeline()
     qp = pdg.permute_in(q0)
@@ -181,13 +183,14 @@ jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
 from repro.dg.solver import make_two_tree_solver, gaussian_pulse
 from repro.dg.partitioned import PartitionedDG
+from repro.launch.mesh import make_mesh
 
 solver = make_two_tree_solver(grid=(8, 4, 4), order=2, extent=(2.0, 1.0, 1.0))
 q0 = gaussian_pulse(solver, center=(0.5, 0.5, 0.5))
 dt = solver.cfl_dt()
 q_ref = None
 for slabs in (2, 4):
-    mesh = jax.make_mesh((slabs,), ("data",))
+    mesh = make_mesh((slabs,), ("data",))
     pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
     q = pdg.permute_out(np.asarray(pdg.run(pdg.permute_in(q0), 4, dt=dt)))
     if q_ref is None:

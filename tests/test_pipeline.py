@@ -17,6 +17,7 @@ from hypothesis_shim import given, settings, st
 from repro.dg.mesh import make_brick
 from repro.dg.rk import LSRK_A, LSRK_B, lsrk45_step, lsrk_coeffs
 from repro.dg.solver import DGSolver, gaussian_pulse, make_two_tree_solver
+from repro.launch.mesh import make_mesh
 from repro.runtime.executor import BlockedDGEngine, NestedPartitionExecutor
 
 
@@ -610,7 +611,7 @@ def test_sharded_pipeline_single_device_mesh():
     solver = _periodic_solver()
     q0 = _rand_state(solver)
     dt = solver.cfl_dt()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
     pipe = pdg.pipeline()
     qp = pdg.permute_in(q0)
@@ -645,7 +646,7 @@ def test_sharded_run_observed_in_scan_channel():
     solver = _periodic_solver()
     q0 = _rand_state(solver)
     dt = solver.cfl_dt()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
     pipe = pdg.pipeline()
     qp = pdg.permute_in(q0)

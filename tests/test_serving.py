@@ -288,9 +288,9 @@ def _engines():
     cl = SimulatedCluster(solver, [NodeProfile(speed=1.0), NodeProfile(speed=2.0)])
     out.append(("SimulatedCluster", cl, q0))
 
-    from jax.sharding import Mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     pdg = PartitionedDG(solver, mesh)
     out.append(("PartitionedDG", pdg, pdg.permute_in(q0)))
     return out
@@ -330,13 +330,13 @@ def test_partitioned_dg_executor_kwarg_removed():
     its one-release window: the kwarg is gone, and the bind_executor +
     observe=True spelling is the only one."""
     import jax
-    from jax.sharding import Mesh
 
     from repro.dg.partitioned import PartitionedDG
     from repro.dg.solver import gaussian_pulse, make_two_tree_solver
+    from repro.launch.mesh import make_mesh
 
     solver = make_two_tree_solver(grid=(4, 2, 2), order=2)
-    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     pdg = PartitionedDG(solver, mesh)
     ex = pdg.make_executor(bucket=4, rebalance_every=0)
     q = pdg.permute_in(gaussian_pulse(solver, width=0.25))

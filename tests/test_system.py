@@ -106,7 +106,7 @@ from repro.optim import OptConfig, init_opt_state
 from repro.parallel.steps import accum_layout, make_shardings, make_train_step
 from repro.launch.specs import train_input_specs
 from repro.launch.hlo_analysis import analyze
-from repro.jax_compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 cfg = smoke_config(get_config("qwen2-7b")).replace(tp_size=2, dtype="bfloat16")
 lm = LM(cfg)
