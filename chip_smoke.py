@@ -17,8 +17,8 @@ injected 2x straggler so the resplice moves work.  The reference is the
 flat ``DGSolver`` with ``kernel_impl="xla"``.
 
 Four chips: ``PartitionedDG`` slabs over a 4-device mesh on 4 x 8192
-elements (128x16x16, the same element size), against the flat XLA solver on
-one chip.
+elements (128x16x16, the same element size), run twice (cold, then warm),
+against the flat XLA solver on one chip.
 
 Everything runs in this one process, with x64 off.  Without a TPU it exits
 non-zero and prints no result.  Its last line is one JSON object naming the
@@ -124,13 +124,21 @@ def paper_problem(kernel_impl: str, **overrides):
 
 
 def horizon(solver):
-    """(steps, dt): the 118 steps of ``configs/dg_wave.py`` at the solver's
-    CFL step.  The config's dt = 1e-3 is unstable on this brick (element
-    size 1/16, cp = 3, order 7: 8x the solver's CFL step), so the steps
-    are kept and dt is the solver's own."""
+    """(steps, dt): the horizon of ``configs/dg_wave.py`` at the solver's
+    CFL step."""
     from repro.configs.dg_wave import CONFIG
 
-    return round(CONFIG.final_time / CONFIG.dt), solver.cfl_dt()
+    return CONFIG.n_steps, solver.cfl_dt()
+
+
+def reference_run(ref, q0, n, dt):
+    """The flat XLA solver with full float32 products: the MXU's default
+    for an f32 dot is one bf16 pass, and the Pallas volume kernel asks for
+    full precision, so both sides of the comparison compute in float32."""
+    import jax
+
+    with jax.default_matmul_precision("highest"):
+        return timed(lambda: ref.run(q0, n, dt=dt))
 
 
 def pulse(solver):
@@ -198,9 +206,15 @@ def one_chip(log: CompileLog, kernel_impl: str = "pallas", **overrides) -> None:
     check(stats.dispatches == 2 + chunks and stats.observe_chunks == chunks,
           "ledger: one dispatch per fused run and per observed chunk")
 
-    q_r, t_ref = timed(lambda: ref.run(q0, n, dt=dt))
+    q_r, t_ref = reference_run(ref, q0, n, dt)
     log.show("xla-ref")
     print(f"[xla-ref] first_call_s (compile + run): {t_ref}")
+    # what the library's default matmul precision does to the XLA path
+    # (a reading, not a check)
+    q_d, _ = timed(lambda: ref.run(q0, n, dt=dt))
+    log.show("xla-default-precision")
+    print(f"[xla-default-precision] max_abs_diff_over_max_abs_q vs xla-ref: "
+          f"{rel_diff(q_d, q_r)}")
     for tag, q in (("fused", q_f), ("observe", q_o)):
         d = rel_diff(q, q_r)
         print(f"[{tag}] max_abs_diff_over_max_abs_q vs xla: {d}")
@@ -215,7 +229,6 @@ def four_chips(log: CompileLog, kernel_impl: str = "pallas",
     solver on one device."""
     import jax
     import numpy as np
-    from jax.sharding import NamedSharding
 
     from repro.dg.partitioned import PartitionedDG
     from repro.launch.mesh import make_mesh
@@ -230,25 +243,28 @@ def four_chips(log: CompileLog, kernel_impl: str = "pallas",
           f"K {solver.mesh.K}, float32, {n} steps, dt {dt}, kernel_impl {kernel_impl}")
     q0 = pulse(solver)
     pdg = PartitionedDG(solver=solver, mesh_axes=mesh)
-    # permute on the host and place the slabs straight onto their devices:
-    # a gather of the whole 4 x 8192-element state on one chip takes a
-    # lane-padded layout that needs 16 GB of its HBM
-    qp0 = jax.device_put(pdg.permute_in(np.asarray(q0)),
-                         NamedSharding(mesh, pdg.spec_q))
-    # one run only: four chips cost four times as much per second
-    qp, t_cold = timed(lambda: pdg.run(qp0, n, dt=dt))
+    qp0 = pdg.permute_in(q0)
+    in_devs = [s.device for s in qp0.addressable_shards]
+    check(len(in_devs) == N_DEV and set(in_devs) == set(mesh.devices.flat),
+          f"permute_in: one slab on each of {N_DEV} devices")
+    qp_cold, t_cold = timed(lambda: pdg.run(qp0, n, dt=dt))
     log.show("sharded")
+    qp, t_warm = timed(lambda: pdg.run(qp0, n, dt=dt))
     print(f"[sharded] first_call_s (compile + run): {t_cold}")
+    print(f"[sharded] warm_run_s: {t_warm}")
+    print(f"[sharded] steps_per_s (smoke, not a benchmark): {n / t_warm}")
+    check(np.array_equal(np.asarray(qp_cold), np.asarray(qp)),
+          "sharded: warm run repeats the cold run")
     shard_devs = {s.device for s in qp.addressable_shards}
     print(f"[sharded] shard devices: {sorted(str(d) for d in shard_devs)}")
     check(len(shard_devs) == N_DEV and shard_devs == set(mesh.devices.flat),
           f"sharded: the state spans {N_DEV} distinct devices")
     stats = pdg.pipeline().stats
     print(f"[ledger] dispatches: {stats.dispatches} steps_run: {stats.steps_run}")
-    check(stats.dispatches == 1, "ledger: one dispatch for the sharded run")
+    check(stats.dispatches == 2, "ledger: one dispatch per sharded run")
 
-    q_s = pdg.permute_out(np.asarray(qp))
-    q_r, t_ref = timed(lambda: ref.run(q0, n, dt=dt))
+    q_s = pdg.permute_out(qp)
+    q_r, t_ref = reference_run(ref, q0, n, dt)
     log.show("xla-ref")
     print(f"[xla-ref] first_call_s (compile + run): {t_ref}")
     d = rel_diff(q_s, q_r)

@@ -15,8 +15,10 @@ class DGConfig:
     cp: tuple = (1.0, 3.0)
     cs: tuple = (0.0, 2.0)
     rho: tuple = (1.0, 1.0)
-    dt: float = 1e-3
-    final_time: float = 0.118           # 118 steps at dt=1e-3
+    # the run's horizon, each step at the solver's CFL step
+    # (DGSolver.cfl_dt, ~1.28e-4 on this brick): a fixed dt = 1e-3 is 8x
+    # over that bound at order 7 and the run blows up
+    n_steps: int = 118
 
 
 CONFIG = DGConfig()

@@ -41,10 +41,11 @@ OPPOSITE = (1, 0, 3, 2, 5, 4)
 def deriv(u: jnp.ndarray, D: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Apply the differentiation matrix along element axis (0,1,2) of
     u (K, F, M, M, M) — the paper's IIAX/IAIX/AIIX tensor applications."""
-    spec = ("am,kfmjl->kfajl", "am,kfiml->kfial", "am,kfijm->kfija")[axis]
-    # full f32 products on the TPU MXU (its default is one bf16 pass, which
-    # costs the derivative three digits); the CPU computes f32 either way
-    return jnp.einsum(spec, D, u, precision=jax.lax.Precision.HIGHEST)
+    if axis == 0:
+        return jnp.einsum("am,kfmjl->kfajl", D, u)
+    if axis == 1:
+        return jnp.einsum("am,kfiml->kfial", D, u)
+    return jnp.einsum("am,kfijm->kfija", D, u)
 
 
 def stress(q: jnp.ndarray, lam: jnp.ndarray, mu: jnp.ndarray) -> jnp.ndarray:

@@ -173,31 +173,38 @@ class PartitionedDG:
             s.mesh.neighbors, s.mesh.grid, self.P
         )
         self.inv_perm = inv
-        dt = jnp.dtype(s.dtype)
-        # global sharded tables: (P * ext_n, ...) with one slab's extended
-        # block per device (materials are static — only q rides the ring)
-        ids = ext_ids.reshape(-1)
-        self.nbr_e = jnp.asarray(nbr_ext.reshape(-1, 6))
-        rho = np.asarray(s.rho)[self.order_perm][ids]
-        lam = np.asarray(s.lam)[self.order_perm][ids]
-        mu = np.asarray(s.mu)[self.order_perm][ids]
-        self.rho_e = jnp.asarray(rho, dt)
-        self.lam_e = jnp.asarray(lam, dt)
-        self.mu_e = jnp.asarray(mu, dt)
-        self.cp_e = jnp.sqrt((self.lam_e + 2 * self.mu_e) / self.rho_e)
-        self.cs_e = jnp.sqrt(self.mu_e / self.rho_e)
         self.spec_q = P(self.axis, None, None, None, None)
         self.spec_e = P(self.axis)
+        # global sharded tables: (P * ext_n, ...) with one slab's extended
+        # block per device (materials are static — only q rides the ring)
+        ids = self.order_perm[ext_ids.reshape(-1)]
+        line = lambda v: self.place(np.asarray(v)[ids], self.spec_e)
+        self.nbr_e = self.place(nbr_ext.reshape(-1, 6), P(self.axis, None))
+        self.rho_e, self.lam_e, self.mu_e = line(s.rho_j), line(s.lam_j), line(s.mu_j)
+        self.cp_e, self.cs_e = line(s.cp_j), line(s.cs_j)
         self._pipeline = None
         self._step_jit = None
         self._executor = None
 
     # ------------------------------------------------------------------
-    def permute_in(self, q_flat: jnp.ndarray) -> jnp.ndarray:
-        return q_flat[self.order_perm]
+    def place(self, x, spec: P) -> jax.Array:
+        """A host array as a global array sharded by ``spec`` over the
+        mesh: each device receives only its own block, straight from the
+        host (nothing is staged on one device)."""
+        x = np.asarray(x)
+        sharding = jax.sharding.NamedSharding(self.mesh_axes, spec)
+        return jax.make_array_from_callback(x.shape, sharding, lambda idx: x[idx])
 
-    def permute_out(self, q_part: jnp.ndarray) -> jnp.ndarray:
-        return q_part[self.inv_perm]
+    def permute_in(self, q_flat) -> jax.Array:
+        """The flat-order state in slab order, sharded over the mesh axis
+        (``spec_q``).  The permutation runs on the host and each device
+        receives only its slab: a gather of the whole state on one device
+        takes a lane-padded layout many times the state's size."""
+        return self.place(np.asarray(q_flat)[self.order_perm], self.spec_q)
+
+    def permute_out(self, q_part) -> np.ndarray:
+        """The slab-order state back in flat order, on the host."""
+        return np.asarray(q_part)[self.inv_perm]
 
     # ------------------------------------------------------------------
     def _make_schedule(self) -> StepSchedule:

@@ -41,6 +41,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 BE = 16  # default elements per grid step (a multiple of the 8 sublanes)
@@ -66,15 +67,35 @@ def block_elems() -> int:
     return BE if _ACTIVE_BE is None else _ACTIVE_BE
 
 
-def derivative_operators(D: jnp.ndarray, metrics: Tuple[float, float, float]) -> jnp.ndarray:
+def _build_operators(xp, D, metrics):
+    M = D.shape[0]
+    eye = xp.eye(M, dtype=D.dtype)
+    kron3 = lambda a, b, c: xp.kron(xp.kron(a, b), c)
+    ops = (kron3(D, eye, eye), kron3(eye, D, eye), kron3(eye, eye, D))
+    return xp.stack([(op.T * m).astype(D.dtype) for op, m in zip(ops, metrics)])
+
+
+@functools.lru_cache(maxsize=16)
+def _host_operators(D_bytes: bytes, M: int, dtype: str, metrics) -> np.ndarray:
+    D = np.frombuffer(D_bytes, dtype=dtype).reshape(M, M)
+    return _build_operators(np, D, metrics)
+
+
+def derivative_operators(D, metrics: Tuple[float, float, float], dtype=None):
     """(3, M^3, M^3): right-multiplying a row of element nodes by entry
     ``a`` differentiates along element axis ``a`` and scales by
-    ``metrics[a]``.  Built with jnp, so a traced ``D`` works too."""
-    M = D.shape[0]
-    eye = jnp.eye(M, dtype=D.dtype)
-    kron3 = lambda a, b, c: jnp.kron(jnp.kron(a, b), c)
-    ops = (kron3(D, eye, eye), kron3(eye, D, eye), kron3(eye, eye, D))
-    return jnp.stack([op.T * m for op, m in zip(ops, metrics)])
+    ``metrics[a]``.
+
+    A concrete ``D`` (the solver's) gives a host array built once per
+    (D, metrics, dtype) and cached: inside a trace it is a constant of the
+    program, so no step evaluates the Kronecker products.  A traced ``D``
+    builds them with jnp inside the trace.  ``dtype`` defaults to D's."""
+    metrics = tuple(float(m) for m in metrics)
+    dtype = D.dtype if dtype is None else dtype
+    if isinstance(D, jax.core.Tracer):
+        return _build_operators(jnp, D.astype(dtype), metrics)
+    D = np.asarray(D, dtype)
+    return _host_operators(D.tobytes(), D.shape[0], D.dtype.str, metrics)
 
 
 def _volume_kernel(q_ref, ops_ref, mat_ref, out_ref, *, N: int, BE: int):
@@ -136,7 +157,7 @@ def dg_volume_pallas(
         lam = jnp.concatenate([lam, jnp.ones(pad, lam.dtype)])
         mu = jnp.concatenate([mu, jnp.ones(pad, mu.dtype)])
     Kp = K + pad
-    ops = derivative_operators(jnp.asarray(D, q.dtype), tuple(float(m) for m in metrics))
+    ops = derivative_operators(D, metrics, q.dtype)
     mats = jnp.stack([rho, lam, mu], axis=1).astype(q.dtype)
 
     out = pl.pallas_call(

@@ -725,13 +725,22 @@ class ShardedStepPipeline:
 
     def _sharded_copy(self, x):
         """A fresh buffer with the pipeline's q-sharding — what the donated
-        carry consumes, so the caller's array survives every call."""
+        carry consumes, so the caller's array survives every call.  An
+        array already so sharded is copied in place on its devices, a
+        device array on another sharding is resharded, and a host array
+        goes to each device as its own slab, never staged whole on one
+        device."""
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding
 
         p = self.pdg
-        return jax.device_put(jnp.copy(x), NamedSharding(p.mesh_axes, p.spec_q))
+        sharding = NamedSharding(p.mesh_axes, p.spec_q)
+        if not isinstance(x, jax.Array):
+            return p.place(x, p.spec_q)
+        if x.sharding.is_equivalent_to(sharding, x.ndim):
+            return jnp.copy(x)
+        return jax.device_put(x, sharding)
 
     def rhs(self, q):
         """One fused sharded rhs evaluation (the differential-test probe)."""
@@ -753,7 +762,8 @@ class ShardedStepPipeline:
 
         dt = dt if dt is not None else self.solver.cfl_dt()
         q = self._sharded_copy(q)
-        res = self._sharded_copy(jnp.zeros_like(q) if res is None else res)
+        # zeros_like keeps q's sharding: a fresh buffer on every device
+        res = jnp.zeros_like(q) if res is None else self._sharded_copy(res)
         fn = self._run_fn()
         self.stats.record(1, int(n_steps))
         q, _ = fn(q, res, jnp.asarray(dt, q.dtype),
@@ -772,18 +782,17 @@ class ShardedStepPipeline:
         element counts; returns ``(q, CalibrationReport)``."""
         import jax
         import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec
+        from jax.sharding import PartitionSpec
 
         p = self.pdg
         dt = dt if dt is not None else self.solver.cfl_dt()
         if price is None:
             price = np.full(p.P, float(p.K_loc))
         dtype = jnp.float64 if q.dtype == jnp.float64 else jnp.float32
-        sh = NamedSharding(p.mesh_axes, PartitionSpec(p.axis))
-        price = jax.device_put(jnp.asarray(price, dtype), sh)
-        acc = jax.device_put(jnp.zeros((p.P,), dtype), sh)
+        price = p.place(np.asarray(price, dtype), PartitionSpec(p.axis))
+        acc = p.place(np.zeros((p.P,), dtype), PartitionSpec(p.axis))
         q = self._sharded_copy(q)
-        res = self._sharded_copy(jnp.zeros_like(q))
+        res = jnp.zeros_like(q)
         fn = self._priced_run_fn()
         self.stats.record(1, int(n_steps))
         t0 = time.perf_counter()

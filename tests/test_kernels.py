@@ -35,6 +35,32 @@ def test_dg_volume_kernel(K, order, dt):
     np.testing.assert_allclose(out, want, **_tol(dt))
 
 
+def test_dg_volume_operators_built_once_outside_the_trace():
+    """A concrete D gives cached host operators, which enter the traced
+    program as a constant: no op of the trace builds an (M^3, M^3) array.
+    A traced D builds the same operators inside the trace."""
+    from repro.kernels.dg_volume import derivative_operators
+
+    order, K = 3, 8
+    M, N = order + 1, (order + 1) ** 3
+    x, _ = lgl_nodes_weights(order)
+    D = jnp.asarray(diff_matrix(x), "float32")
+    metrics = (2.0, 3.0, 4.0)
+    ops = derivative_operators(D, metrics)
+    assert isinstance(ops, np.ndarray) and ops.shape == (3, N, N) and ops.dtype == np.float32
+    assert derivative_operators(D, metrics) is ops
+    traced = jax.jit(lambda d: derivative_operators(d, metrics))(D)
+    np.testing.assert_array_equal(np.asarray(traced), ops)
+
+    q = jnp.zeros((K, 9, M, M, M), jnp.float32)
+    mat = jnp.ones(K, jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q: dg_volume_pallas(
+        q, D, metrics, mat, mat, mat, interpret=True))(q)
+    built = [e for e in jaxpr.jaxpr.eqns
+             if any(getattr(v.aval, "shape", ())[-2:] == (N, N) for v in e.outvars)]
+    assert not built, built
+
+
 @pytest.mark.parametrize("F,M", [(10, 8), (200, 4), (128, 8), (300, 8)])
 @pytest.mark.parametrize("dt", ["float32", "float64"])
 @pytest.mark.parametrize("axis,sign", [(0, 1.0), (1, -1.0), (2, 1.0)])

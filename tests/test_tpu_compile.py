@@ -71,11 +71,14 @@ def _f32(shape, sharding):
 
 
 def test_volume_kernel_compiles(one_chip):
+    """With the solver's concrete ``D``, as every engine calls it."""
+    from repro.dg.basis import diff_matrix, lgl_nodes_weights
     from repro.kernels.dg_volume import dg_volume_pallas
 
-    f = jax.jit(lambda q, D, rho, lam, mu: dg_volume_pallas(
+    D = jnp.asarray(diff_matrix(lgl_nodes_weights(ORDER)[0]), jnp.float32)
+    f = jax.jit(lambda q, rho, lam, mu: dg_volume_pallas(
         q, D, (32.0, 32.0, 32.0), rho, lam, mu, interpret=False))
-    _check(f.lower(_f32((K, 9, M, M, M), one_chip), _f32((M, M), one_chip),
+    _check(f.lower(_f32((K, 9, M, M, M), one_chip),
                    *[_f32((K,), one_chip)] * 3).compile())
 
 
