@@ -166,53 +166,56 @@ def surface_rhs(
     int_flux/godonov_flux hot-spot as a TPU kernel) — one instantiation per
     face direction, exactly the solver's face loop.
     """
-    S = stress(q, lam, mu)
-    out = jnp.zeros_like(q)
-    mats = {"rho": rho, "cp": cp, "cs": cs, "mu": mu}
-    for face in range(6):
-        ax = FACE_AXIS[face]
-        sign = FACE_SIGN[face]
-        nbr = neighbors[:, face]
-        has_nbr = nbr >= 0
-        skip = nbr == -2  # cross-partition face: handled by the halo pass
-        nbr_safe = jnp.maximum(nbr, 0)
+    # the flux stage: stress, face traces, neighbour gathers, the Riemann
+    # correction (kernel and its relayouts), lift and the face add
+    with jax.named_scope("dg.flux"):
+        S = stress(q, lam, mu)
+        out = jnp.zeros_like(q)
+        mats = {"rho": rho, "cp": cp, "cs": cs, "mu": mu}
+        for face in range(6):
+            ax = FACE_AXIS[face]
+            sign = FACE_SIGN[face]
+            nbr = neighbors[:, face]
+            has_nbr = nbr >= 0
+            skip = nbr == -2  # cross-partition face: handled by the halo pass
+            nbr_safe = jnp.maximum(nbr, 0)
 
-        Sm = extract_face(S, face)
-        vm = extract_face(q[:, 6:9], face)
-        Sp_all = extract_face(S, OPPOSITE[face])
-        vp_all = extract_face(q[:, 6:9], OPPOSITE[face])
-        Sp = Sp_all[nbr_safe]
-        vp = vp_all[nbr_safe]
-        # physical boundary: traction-free mirror [v]=0, S_j = 2 S^- n
-        hn = has_nbr[:, None, None, None]
-        Sp = jnp.where(hn, Sp, -Sm)  # S_j = Sm - Sp = 2 Sm
-        vp = jnp.where(hn, vp, vm)  # v_j = 0
-        mat_m = mats
-        mat_p = {k: jnp.where(has_nbr, v[nbr_safe], v) for k, v in mats.items()}
+            Sm = extract_face(S, face)
+            vm = extract_face(q[:, 6:9], face)
+            Sp_all = extract_face(S, OPPOSITE[face])
+            vp_all = extract_face(q[:, 6:9], OPPOSITE[face])
+            Sp = Sp_all[nbr_safe]
+            vp = vp_all[nbr_safe]
+            # physical boundary: traction-free mirror [v]=0, S_j = 2 S^- n
+            hn = has_nbr[:, None, None, None]
+            Sp = jnp.where(hn, Sp, -Sm)  # S_j = Sm - Sp = 2 Sm
+            vp = jnp.where(hn, vp, vm)  # v_j = 0
+            mat_m = mats
+            mat_p = {k: jnp.where(has_nbr, v[nbr_safe], v) for k, v in mats.items()}
 
-        if kernel_impl == "xla":
-            FE, Fv = riemann_correction(Sm, vm, Sp, vp, ax, sign, mat_m, mat_p)
-        else:  # pallas | interpret — the flux kernel behind the same switch
-            from repro.kernels.dg_flux import dg_flux_pallas
+            if kernel_impl == "xla":
+                FE, Fv = riemann_correction(Sm, vm, Sp, vp, ax, sign, mat_m, mat_p)
+            else:  # pallas | interpret — the flux kernel behind the same switch
+                from repro.kernels.dg_flux import dg_flux_pallas
 
-            mats8 = jnp.stack(
-                [mat_m["rho"], mat_m["cp"], mat_m["cs"], mat_m["mu"],
-                 mat_p["rho"], mat_p["cp"], mat_p["cs"], mat_p["mu"]],
-                axis=1,
-            )
-            FE, Fv = dg_flux_pallas(Sm, vm, Sp, vp, mats8, ax, sign,
-                                    interpret=_interpret(kernel_impl))
-        corr = jnp.concatenate([FE, Fv / rho[:, None, None, None]], axis=1)  # Q^-1 on v rows
-        corr = -lift[ax] * corr
-        corr = jnp.where(skip[:, None, None, None], 0.0, corr)
-        last = q.shape[2 + ax] - 1
-        idx = 0 if sign < 0 else last
-        if ax == 0:
-            out = out.at[:, :, idx, :, :].add(corr)
-        elif ax == 1:
-            out = out.at[:, :, :, idx, :].add(corr)
-        else:
-            out = out.at[:, :, :, :, idx].add(corr)
+                mats8 = jnp.stack(
+                    [mat_m["rho"], mat_m["cp"], mat_m["cs"], mat_m["mu"],
+                     mat_p["rho"], mat_p["cp"], mat_p["cs"], mat_p["mu"]],
+                    axis=1,
+                )
+                FE, Fv = dg_flux_pallas(Sm, vm, Sp, vp, mats8, ax, sign,
+                                        interpret=_interpret(kernel_impl))
+            corr = jnp.concatenate([FE, Fv / rho[:, None, None, None]], axis=1)  # Q^-1 on v rows
+            corr = -lift[ax] * corr
+            corr = jnp.where(skip[:, None, None, None], 0.0, corr)
+            last = q.shape[2 + ax] - 1
+            idx = 0 if sign < 0 else last
+            if ax == 0:
+                out = out.at[:, :, idx, :, :].add(corr)
+            elif ax == 1:
+                out = out.at[:, :, :, idx, :].add(corr)
+            else:
+                out = out.at[:, :, :, :, idx].add(corr)
     return out
 
 
@@ -230,12 +233,13 @@ def _interpret(kernel_impl: str) -> bool:
 def volume_rhs_impl(q, D, metrics, rho, lam, mu, kernel_impl: str = "xla"):
     """``volume_rhs`` behind the kernel switch: ``xla`` is the jnp reference,
     ``pallas``/``interpret`` run the paper's volume_loop as a TPU kernel."""
-    if kernel_impl == "xla":
-        return volume_rhs(q, D, metrics, rho, lam, mu)
-    from repro.kernels.dg_volume import dg_volume_pallas
+    with jax.named_scope("dg.volume"):
+        if kernel_impl == "xla":
+            return volume_rhs(q, D, metrics, rho, lam, mu)
+        from repro.kernels.dg_volume import dg_volume_pallas
 
-    return dg_volume_pallas(q, D, metrics, rho, lam, mu,
-                            interpret=_interpret(kernel_impl))
+        return dg_volume_pallas(q, D, metrics, rho, lam, mu,
+                                interpret=_interpret(kernel_impl))
 
 
 def dg_rhs(q, D, metrics, lift, neighbors, rho, lam, mu, cp, cs, kernel_impl: str = "xla"):

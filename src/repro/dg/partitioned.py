@@ -220,12 +220,14 @@ class PartitionedDG:
         def boundary(st):
             # the pack: both slab-edge element layers (contiguous slices)
             q = st["q"]
-            return {"lo": q[:L], "hi": q[-L:]}
+            with jax.named_scope("dg.halo"):
+                return {"lo": q[:L], "hi": q[-L:]}
 
         def exchange(send, st):
-            from_prev, from_next = halo_exchange_1d(
-                send["lo"], send["hi"], self.axis, wrap=self.x_wrap
-            )
+            with jax.named_scope("dg.halo"):
+                from_prev, from_next = halo_exchange_1d(
+                    send["lo"], send["hi"], self.axis, wrap=self.x_wrap
+                )
             return {"from_prev": from_prev, "from_next": from_next}
 
         def interior(st):
@@ -243,13 +245,15 @@ class PartitionedDG:
             # then-flux structure as BlockedDGEngine, so every own row's six
             # face corrections are bitwise the flat solver's (halo rows'
             # output is dropped by the slice)
-            q_ext = jnp.concatenate([st["q"], recv["from_prev"], recv["from_next"]])
+            with jax.named_scope("dg.gather"):
+                q_ext = jnp.concatenate([st["q"], recv["from_prev"], recv["from_next"]])
             sur = surface_rhs(
                 q_ext, st["nbr"], s.lift,
                 st["rho"], st["lam"], st["mu"], st["cp"], st["cs"],
                 kernel_impl=s.kernel_impl,
             )
-            return out + sur[:per]
+            with jax.named_scope("dg.scatter"):
+                return out + sur[:per]
 
         return StepSchedule(boundary=boundary, exchange=exchange,
                             interior=interior, correction=correction, name="slab-spmd")

@@ -72,9 +72,11 @@ def lsrk45_step(q, res, rhs_fn, dt):
     dt = jnp.asarray(dt, dtype)
 
     def stage(carry, ab):
-        q, res = carry
-        res = ab[0] * res + dt * rhs_fn(q)
-        q = q + ab[1] * res
+        # the stage update; the rhs stages inside it carry their own scopes
+        with jax.named_scope("dg.lsrk"):
+            q, res = carry
+            res = ab[0] * res + dt * rhs_fn(q)
+            q = q + ab[1] * res
         return (q, res), None
 
     (q, res), _ = jax.lax.scan(stage, (q, res), lsrk_coeffs(dtype))

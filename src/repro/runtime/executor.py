@@ -452,8 +452,12 @@ class NestedPartitionExecutor:
         factors are applied here, inside ``observe``, exactly once) and
         advance the rebalance schedule by the chunk's steps.  Returns the
         applied ``Plan`` when the schedule fired, else ``None``."""
-        self.observe(np.asarray(report.step_s))
-        return self.advance(int(n_steps))
+        from jax.profiler import TraceAnnotation
+
+        # host span: observe, then (when the schedule fires) solve and resplice
+        with TraceAnnotation("dg.rebalance"):
+            self.observe(np.asarray(report.step_s))
+            return self.advance(int(n_steps))
 
     # -- solve / resplice ---------------------------------------------------
 
@@ -838,61 +842,63 @@ class BlockedDGEngine:
         interior/halo index sets).  No kernel recompiles unless a brand-new
         padded size appears."""
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
 
-        s = self.solver
-        part = self.executor.partition
-        K = s.mesh.K
-        nbr = s.mesh.neighbors
-        bucket = self.executor.bucket
-        dt = jnp.dtype(s.dtype)
-        # the (K+1)-row scatter target (row K is the dump row for padded
-        # block rows) is shape-invariant across resplices — hoisted here,
-        # and shared per solver (a SimulatedCluster's N engines reuse one
-        # buffer), so rhs() never allocates a fresh zeros per evaluation
-        if getattr(s, "_scatter_base", None) is None:
-            s._scatter_base = jnp.zeros((K + 1, 9, s.M, s.M, s.M), dt)
-        self._scatter_base = s._scatter_base
-        blocks = []
-        for p, node in enumerate(part.nodes):
-            own = np.asarray(node.elements, dtype=np.int64)
-            if len(own) == 0 or (self.only_blocks is not None and p not in self.only_blocks):
-                blocks.append(None)
-                continue
-            halo = np.asarray(node.halo, dtype=np.int64)
-            ext = np.concatenate([own, halo])
-            pad = pad_to_bucket(len(ext), bucket)
-            pad_own = pad_to_bucket(len(own), bucket)
-            self.pads_seen.update((pad, pad_own))
-            ext_pad = np.concatenate([ext, np.zeros(pad - len(ext), dtype=np.int64)])
-            own_pad = np.concatenate([own, np.zeros(pad_own - len(own), dtype=np.int64)])
-            halo_pad = ext_pad[len(own):]  # halo ++ zero-pad: concat target
-            lut = np.full(K, -1, dtype=np.int64)
-            lut[ext] = np.arange(len(ext))
-            nbr_ext = nbr[ext_pad]
-            # own rows: every real neighbour is in ext by construction, so
-            # lut resolves it; -1 (physical boundary) is preserved.  halo and
-            # pad rows may point outside ext -> -1; their output is dumped.
-            nbr_local = np.where(nbr_ext >= 0, lut[np.clip(nbr_ext, 0, None)], -1)
-            scat = np.concatenate([own, np.full(pad_own - len(own), K, dtype=np.int64)])
-            blocks.append(
-                {
-                    "own": jnp.asarray(own),
-                    "own_pad": jnp.asarray(own_pad),
-                    "halo": jnp.asarray(halo_pad),
-                    "nbr_local": jnp.asarray(nbr_local),
-                    "scat": jnp.asarray(scat),
-                    "rho": jnp.asarray(s.rho[ext_pad], dt),
-                    "lam": jnp.asarray(s.lam[ext_pad], dt),
-                    "mu": jnp.asarray(s.mu[ext_pad], dt),
-                    "cp": jnp.asarray(np.sqrt((s.lam + 2 * s.mu) / s.rho)[ext_pad], dt),
-                    "cs": jnp.asarray(np.sqrt(s.mu / s.rho)[ext_pad], dt),
-                    "rho_o": jnp.asarray(s.rho[own_pad], dt),
-                    "lam_o": jnp.asarray(s.lam[own_pad], dt),
-                    "mu_o": jnp.asarray(s.mu[own_pad], dt),
-                    "n_own": len(own),
-                }
-            )
-        self._blocks = blocks
+        with TraceAnnotation("dg.tables"):
+            s = self.solver
+            part = self.executor.partition
+            K = s.mesh.K
+            nbr = s.mesh.neighbors
+            bucket = self.executor.bucket
+            dt = jnp.dtype(s.dtype)
+            # the (K+1)-row scatter target (row K is the dump row for padded
+            # block rows) is shape-invariant across resplices — hoisted here,
+            # and shared per solver (a SimulatedCluster's N engines reuse one
+            # buffer), so rhs() never allocates a fresh zeros per evaluation
+            if getattr(s, "_scatter_base", None) is None:
+                s._scatter_base = jnp.zeros((K + 1, 9, s.M, s.M, s.M), dt)
+            self._scatter_base = s._scatter_base
+            blocks = []
+            for p, node in enumerate(part.nodes):
+                own = np.asarray(node.elements, dtype=np.int64)
+                if len(own) == 0 or (self.only_blocks is not None and p not in self.only_blocks):
+                    blocks.append(None)
+                    continue
+                halo = np.asarray(node.halo, dtype=np.int64)
+                ext = np.concatenate([own, halo])
+                pad = pad_to_bucket(len(ext), bucket)
+                pad_own = pad_to_bucket(len(own), bucket)
+                self.pads_seen.update((pad, pad_own))
+                ext_pad = np.concatenate([ext, np.zeros(pad - len(ext), dtype=np.int64)])
+                own_pad = np.concatenate([own, np.zeros(pad_own - len(own), dtype=np.int64)])
+                halo_pad = ext_pad[len(own):]  # halo ++ zero-pad: concat target
+                lut = np.full(K, -1, dtype=np.int64)
+                lut[ext] = np.arange(len(ext))
+                nbr_ext = nbr[ext_pad]
+                # own rows: every real neighbour is in ext by construction, so
+                # lut resolves it; -1 (physical boundary) is preserved.  halo and
+                # pad rows may point outside ext -> -1; their output is dumped.
+                nbr_local = np.where(nbr_ext >= 0, lut[np.clip(nbr_ext, 0, None)], -1)
+                scat = np.concatenate([own, np.full(pad_own - len(own), K, dtype=np.int64)])
+                blocks.append(
+                    {
+                        "own": jnp.asarray(own),
+                        "own_pad": jnp.asarray(own_pad),
+                        "halo": jnp.asarray(halo_pad),
+                        "nbr_local": jnp.asarray(nbr_local),
+                        "scat": jnp.asarray(scat),
+                        "rho": jnp.asarray(s.rho[ext_pad], dt),
+                        "lam": jnp.asarray(s.lam[ext_pad], dt),
+                        "mu": jnp.asarray(s.mu[ext_pad], dt),
+                        "cp": jnp.asarray(np.sqrt((s.lam + 2 * s.mu) / s.rho)[ext_pad], dt),
+                        "cs": jnp.asarray(np.sqrt(s.mu / s.rho)[ext_pad], dt),
+                        "rho_o": jnp.asarray(s.rho[own_pad], dt),
+                        "lam_o": jnp.asarray(s.lam[own_pad], dt),
+                        "mu_o": jnp.asarray(s.mu[own_pad], dt),
+                        "n_own": len(own),
+                    }
+                )
+            self._blocks = blocks
 
     # -- execution ----------------------------------------------------------
 

@@ -153,10 +153,13 @@ class FusedStepPipeline:
         self._sig = None
 
     def _build_tables(self) -> None:
-        if self.layout == "envelope":
-            self._build_tables_envelope()
-        else:
-            self._build_tables_grouped()
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("dg.tables"):
+            if self.layout == "envelope":
+                self._build_tables_envelope()
+            else:
+                self._build_tables_grouped()
 
     def _build_tables_envelope(self) -> None:
         """Pad EVERY block to the common envelope ``(env, env_own)`` = (max
@@ -337,6 +340,8 @@ class FusedStepPipeline:
         and step loop trace this body once, so the recorded numbers are the
         per-kernel launch sites baked into the compiled program per rhs
         evaluation (the quantity the dispatch-count regression tests pin)."""
+        import jax
+
         from repro.dg.operators import surface_rhs, volume_rhs_impl
 
         s = self.solver
@@ -350,23 +355,29 @@ class FusedStepPipeline:
             out = base
             for (pad, pad_own, B, _gid), T in zip(sig, tables):
                 counts["volume"] += 1
+                with jax.named_scope("dg.gather"):
+                    q_own = q[T["own_pad"]]
                 vol = volume_rhs_impl(
-                    q[T["own_pad"]], D, metrics,
+                    q_own, D, metrics,
                     T["rho_o"], T["lam_o"], T["mu_o"], kernel_impl=impl,
                 )
                 counts["surface"] += 1
+                with jax.named_scope("dg.gather"):
+                    q_ext = q[T["ext"]]
                 sur = surface_rhs(
-                    q[T["ext"]], T["nbr"], lift,
+                    q_ext, T["nbr"], lift,
                     T["rho"], T["lam"], T["mu"], T["cp"], T["cs"],
                     kernel_impl=impl,
                 )
-                # rows past each block's own count are dump rows; fold the
-                # leading pad_own surface rows of every block into its volume
-                sur_own = sur.reshape((B, pad) + sur.shape[1:])[:, :pad_own]
-                sur_own = sur_own.reshape((B * pad_own,) + sur.shape[1:])
-                out = out.at[T["scat"]].set(vol + sur_own)
+                with jax.named_scope("dg.scatter"):
+                    # rows past each block's own count are dump rows; fold the
+                    # leading pad_own surface rows of every block into its volume
+                    sur_own = sur.reshape((B, pad) + sur.shape[1:])[:, :pad_own]
+                    sur_own = sur_own.reshape((B * pad_own,) + sur.shape[1:])
+                    out = out.at[T["scat"]].set(vol + sur_own)
             launch_sites[sig] = counts
-            return out[:K]
+            with jax.named_scope("dg.scatter"):
+                return out[:K]
 
         return rhs
 
@@ -492,25 +503,34 @@ class FusedStepPipeline:
         ``runtime.cluster.SimulatedCluster`` prices its virtual link inside
         the scan."""
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
 
-        dt = dt if dt is not None else self.solver.cfl_dt()
-        self._ensure()
-        q = jnp.copy(q)
-        res = jnp.zeros_like(q) if res is None else jnp.copy(res)
-        base = self.engine.scatter_base(q)
-        self.stats.record(1, int(n_steps))
-        if price is None:
-            fn = self._run_fn(self._sig)
-            q, _ = fn(q, res, dt, int(n_steps), self._tables, base)
+        # host spans: all host work of one dispatch, from entry to the
+        # return of the compiled call (the operands' copies, then the call)
+        with TraceAnnotation("dg.dispatch"):
+            dt = dt if dt is not None else self.solver.cfl_dt()
+            self._ensure()
+            with TraceAnnotation("dg.copy_in"):
+                q = jnp.copy(q)
+                res = jnp.zeros_like(q) if res is None else jnp.copy(res)
+                base = self.engine.scatter_base(q)
+                if price is not None:
+                    price = jnp.asarray(price, dtype=jnp.float64 if q.dtype == jnp.float64
+                                        else jnp.float32)
+                    acc = jnp.zeros_like(price)
+            self.stats.record(1, int(n_steps))
+            if price is None:
+                fn = self._run_fn(self._sig)
+                with TraceAnnotation("dg.enqueue"):
+                    q, _ = fn(q, res, dt, int(n_steps), self._tables, base)
+                self._record_launches()
+                return q
+            fn = self._priced_run_fn(self._sig)
+            with TraceAnnotation("dg.enqueue"):
+                q, _, acc = fn(q, res, acc, dt, int(n_steps),
+                               self._tables, base, price)
             self._record_launches()
-            return q
-        price = jnp.asarray(price, dtype=jnp.float64 if q.dtype == jnp.float64
-                            else jnp.float32)
-        fn = self._priced_run_fn(self._sig)
-        q, _, acc = fn(q, res, jnp.zeros_like(price), dt, int(n_steps),
-                       self._tables, base, price)
-        self._record_launches()
-        return q, acc
+            return q, acc
 
     def run_observed(self, q, n_steps: int, dt: Optional[float] = None,
                      price=None, attribute_wall: bool = True,
@@ -540,6 +560,7 @@ class FusedStepPipeline:
         failure leaves ``q``, the ledger and the executor schedule
         untouched, so a supervised retry replays the chunk exactly."""
         import jax
+        from jax.profiler import TraceAnnotation
 
         if injector is not None:
             injector.maybe_fail(step)
@@ -549,7 +570,8 @@ class FusedStepPipeline:
             )
         t0 = time.perf_counter()
         q, acc = self.run(q, n_steps, dt=dt, price=price)
-        jax.block_until_ready(q)
+        with TraceAnnotation("dg.sync"):
+            jax.block_until_ready(q)
         wall = time.perf_counter() - t0
         self.stats.record_chunk()
         acc = np.asarray(acc, dtype=np.float64)
@@ -759,16 +781,21 @@ class ShardedStepPipeline:
     def run(self, q, n_steps: int, dt: Optional[float] = None, res=None):
         """Advance ``n_steps`` as ONE host dispatch across all devices."""
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
 
-        dt = dt if dt is not None else self.solver.cfl_dt()
-        q = self._sharded_copy(q)
-        # zeros_like keeps q's sharding: a fresh buffer on every device
-        res = jnp.zeros_like(q) if res is None else self._sharded_copy(res)
-        fn = self._run_fn()
-        self.stats.record(1, int(n_steps))
-        q, _ = fn(q, res, jnp.asarray(dt, q.dtype),
-                  jnp.asarray(int(n_steps), jnp.int32), *self.pdg._operands())
-        return q
+        with TraceAnnotation("dg.dispatch"):
+            dt = dt if dt is not None else self.solver.cfl_dt()
+            with TraceAnnotation("dg.copy_in"):
+                q = self._sharded_copy(q)
+                # zeros_like keeps q's sharding: a fresh buffer on every device
+                res = jnp.zeros_like(q) if res is None else self._sharded_copy(res)
+                dt_j = jnp.asarray(dt, q.dtype)
+                n_j = jnp.asarray(int(n_steps), jnp.int32)
+            fn = self._run_fn()
+            self.stats.record(1, int(n_steps))
+            with TraceAnnotation("dg.enqueue"):
+                q, _ = fn(q, res, dt_j, n_j, *self.pdg._operands())
+            return q
 
     def run_observed(self, q, n_steps: int, dt: Optional[float] = None,
                      price=None, attribute_wall: bool = True):
@@ -782,24 +809,29 @@ class ShardedStepPipeline:
         element counts; returns ``(q, CalibrationReport)``."""
         import jax
         import jax.numpy as jnp
+        from jax.profiler import TraceAnnotation
         from jax.sharding import PartitionSpec
 
         p = self.pdg
-        dt = dt if dt is not None else self.solver.cfl_dt()
-        if price is None:
-            price = np.full(p.P, float(p.K_loc))
-        dtype = jnp.float64 if q.dtype == jnp.float64 else jnp.float32
-        price = p.place(np.asarray(price, dtype), PartitionSpec(p.axis))
-        acc = p.place(np.zeros((p.P,), dtype), PartitionSpec(p.axis))
-        q = self._sharded_copy(q)
-        res = jnp.zeros_like(q)
-        fn = self._priced_run_fn()
-        self.stats.record(1, int(n_steps))
-        t0 = time.perf_counter()
-        q, _, acc = fn(q, res, acc, jnp.asarray(dt, q.dtype),
-                       jnp.asarray(int(n_steps), jnp.int32), price,
-                       *p._operands())
-        jax.block_until_ready(q)
+        with TraceAnnotation("dg.dispatch"):
+            dt = dt if dt is not None else self.solver.cfl_dt()
+            if price is None:
+                price = np.full(p.P, float(p.K_loc))
+            dtype = jnp.float64 if q.dtype == jnp.float64 else jnp.float32
+            with TraceAnnotation("dg.copy_in"):
+                price = p.place(np.asarray(price, dtype), PartitionSpec(p.axis))
+                acc = p.place(np.zeros((p.P,), dtype), PartitionSpec(p.axis))
+                q = self._sharded_copy(q)
+                res = jnp.zeros_like(q)
+                dt_j = jnp.asarray(dt, q.dtype)
+                n_j = jnp.asarray(int(n_steps), jnp.int32)
+            fn = self._priced_run_fn()
+            self.stats.record(1, int(n_steps))
+            t0 = time.perf_counter()
+            with TraceAnnotation("dg.enqueue"):
+                q, _, acc = fn(q, res, acc, dt_j, n_j, price, *p._operands())
+        with TraceAnnotation("dg.sync"):
+            jax.block_until_ready(q)
         wall = time.perf_counter() - t0
         self.stats.record_chunk()
         acc = np.asarray(acc, dtype=np.float64)
