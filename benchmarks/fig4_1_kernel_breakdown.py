@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import emit, timeit
-from repro.dg.operators import extract_face, surface_rhs, volume_rhs
+from repro.dg.operators import face_traces, surface_rhs, volume_rhs
 from repro.dg.rk import lsrk45_step
 from repro.dg.solver import gaussian_pulse, make_two_tree_solver
 
@@ -68,7 +68,7 @@ def run(grid=(8, 8, 8), order=5, smoke=False, autotune_cache=None):
 
     vol = jax.jit(lambda q: volume_rhs(q, s.D, s.metrics, s.rho_j, s.lam_j, s.mu_j))
     surf = jax.jit(lambda q: surface_rhs(q, s.neighbors, s.lift, s.rho_j, s.lam_j, s.mu_j, s.cp_j, s.cs_j))
-    interp = jax.jit(lambda q: [extract_face(q, f) for f in range(6)])
+    interp = jax.jit(lambda q: face_traces(q, s.lam_j, s.mu_j))
     rhs = jax.jit(s.rhs)
     rk = jax.jit(lambda q, r: lsrk45_step(q, r, lambda x: x, 1e-3))
 
@@ -103,15 +103,13 @@ def run(grid=(8, 8, 8), order=5, smoke=False, autotune_cache=None):
     pv = jax.jit(lambda q: dg_volume_pallas(
         q, D, (2.0, 2.0, 2.0), ones, ones, jnp.zeros(K, jnp.float32),
         interpret=interpret, be=be))
-    F = K * 3  # ~interior faces each shared by two elements
-    Sm = jnp.asarray(rng.standard_normal((F, 6, M, M)), jnp.float32)
-    vm = jnp.asarray(rng.standard_normal((F, 3, M, M)), jnp.float32)
-    Sp = jnp.asarray(rng.standard_normal((F, 6, M, M)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((F, 3, M, M)), jnp.float32)
-    mats = jnp.asarray(np.abs(rng.standard_normal((F, 8))) + 0.5, jnp.float32)
-    pf = jax.jit(lambda *a: dg_flux_pallas(*a, 0, 1.0, interpret=interpret, bf=bf))
+    F = K * 6  # six faces per element row
+    tm = jnp.asarray(rng.standard_normal((6, 6, M * M, K)), jnp.float32)
+    tp = jnp.asarray(rng.standard_normal((6, 6, M * M, K)), jnp.float32)
+    mat = jnp.asarray(np.abs(rng.standard_normal((6, 10, K))) + 0.5, jnp.float32)
+    pf = jax.jit(lambda *a: dg_flux_pallas(*a, (1.0, 1.0, 1.0), interpret=interpret, bf=bf))
     t_pv = timeit(pv, qk, reps=reps)
-    t_pf = timeit(pf, Sm, vm, Sp, vp, mats, reps=reps)
+    t_pf = timeit(pf, tm, tp, mat, reps=reps)
     emit("fig4_1/pallas_volume", t_pv * 1e6,
          f"BE={be} ({source}) {t_pv/K*1e9:.1f}ns/elem")
     emit("fig4_1/pallas_flux", t_pf * 1e6,

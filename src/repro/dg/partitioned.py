@@ -184,6 +184,7 @@ class PartitionedDG:
         self.cp_e, self.cs_e = line(s.cp_j), line(s.cs_j)
         self._pipeline = None
         self._step_jit = None
+        self._rhs_jit = None
         self._executor = None
 
     # ------------------------------------------------------------------
@@ -274,15 +275,18 @@ class PartitionedDG:
 
     # ------------------------------------------------------------------
     def rhs(self, q_part: jnp.ndarray) -> jnp.ndarray:
-        """Global-view rhs on the permuted state (sharded over the axis)."""
-        f = jax.shard_map(
-            self._rhs_local,
-            mesh=self.mesh_axes,
-            in_specs=(self.spec_q,) + self._operand_specs(),
-            out_specs=self.spec_q,
-            check_vma=False,
-        )
-        return f(q_part, *self._operands())
+        """Global-view rhs on the permuted state (sharded over the axis), one
+        compiled program (run eagerly, ``shard_map`` compiles every
+        primitive of the slab schedule on every call)."""
+        if self._rhs_jit is None:
+            self._rhs_jit = jax.jit(jax.shard_map(
+                self._rhs_local,
+                mesh=self.mesh_axes,
+                in_specs=(self.spec_q,) + self._operand_specs(),
+                out_specs=self.spec_q,
+                check_vma=False,
+            ))
+        return self._rhs_jit(q_part, *self._operands())
 
     def make_executor(self, bucket: int = 16, **kwargs):
         """An online auto-rebalancing executor matching this decomposition

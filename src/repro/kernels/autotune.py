@@ -1,7 +1,8 @@
 """Block-size autotuner for the Pallas DG kernels — measured rooflines.
 
 The hand-derived defaults (BE = 16 elements per volume grid step, BF = 128
-faces per flux grid step) were sized for a TPU MXU/VPU on paper napkin math.
+element rows, six faces each, per flux grid step) were sized for a TPU
+MXU/VPU on paper napkin math.
 Calore et al. (PAPERS.md, lattice-Boltzmann on heterogeneous computers) show
 the last ~2x of a stencil code lives in exactly this per-device-class block
 tuning, and Tzovas & Predari's experimental study shows modeled costs must
@@ -67,7 +68,7 @@ __all__ = [
 ]
 
 DEFAULT_BE_CANDIDATES = (8, 16, 32)
-DEFAULT_BF_CANDIDATES = (128, 256, 512)  # faces are lanes: multiples of 128
+DEFAULT_BF_CANDIDATES = (128, 256, 512)  # element rows are lanes: multiples of 128
 N_STAGES = 5  # LSRK4(5): rhs evaluations per timestep
 FACES_PER_ELEMENT = 6  # our surface_rhs computes all 6 faces of every element
 
@@ -253,7 +254,8 @@ def sweep_flux(
     size_factor: int = 8,
     seed: int = 0,
 ) -> Dict[str, dict]:
-    """Per-candidate ``{sec_per_face, overhead_s}`` for ``dg_flux_pallas``."""
+    """Per-candidate ``{sec_per_face, overhead_s}`` for ``dg_flux_pallas``
+    (one call covers six faces per element row)."""
     import jax
     import jax.numpy as jnp
 
@@ -265,18 +267,17 @@ def sweep_flux(
     for bf in candidates:
         bf = int(bf)
         results = {}
-        for F in (bf, size_factor * bf):
-            Sm = jnp.asarray(rng.standard_normal((F, 6, M, M)), dtype)
-            vm = jnp.asarray(rng.standard_normal((F, 3, M, M)), dtype)
-            Sp = jnp.asarray(rng.standard_normal((F, 6, M, M)), dtype)
-            vp = jnp.asarray(rng.standard_normal((F, 3, M, M)), dtype)
-            mats = jnp.asarray(np.abs(rng.standard_normal((F, 8))) + 0.5, dtype)
+        for R in (bf, size_factor * bf):
+            tm = jnp.asarray(rng.standard_normal((6, 6, M * M, R)), dtype)
+            tp = jnp.asarray(rng.standard_normal((6, 6, M * M, R)), dtype)
+            mat = np.abs(rng.standard_normal((6, 10, R))) + 0.5  # HAS, KEEP > 0
+            mat = jnp.asarray(mat, dtype)
             fn = jax.jit(
-                lambda Sm, vm, Sp, vp, mats, bf=bf: dg_flux_pallas(
-                    Sm, vm, Sp, vp, mats, 0, 1.0, interpret=interpret, bf=bf
+                lambda tm, tp, mat, bf=bf: dg_flux_pallas(
+                    tm, tp, mat, (1.0, 1.0, 1.0), interpret=interpret, bf=bf
                 )
             )
-            results[F] = _median_seconds(lambda: fn(Sm, vm, Sp, vp, mats), reps)
+            results[FACES_PER_ELEMENT * R] = _median_seconds(lambda: fn(tm, tp, mat), reps)
         (n_s, t_s), (n_b, t_b) = sorted(results.items())
         slope, ovh = _two_point_fit(t_s, n_s, t_b, n_b)
         out[str(bf)] = {"sec_per_face": slope, "overhead_s": ovh,

@@ -28,10 +28,10 @@ def dg_volume(q, D, metrics, rho, lam, mu, impl: str = "xla"):
     return dg_volume_pallas(q, D, metrics, rho, lam, mu, interpret=(impl == "interpret"))
 
 
-def dg_flux(Sm, vm, Sp, vp, mats, axis, sign, impl: str = "xla"):
+def dg_flux(tm, tp, mat, scale, impl: str = "xla"):
     if impl == "xla":
-        return ref.dg_flux_ref(Sm, vm, Sp, vp, mats, axis, sign)
-    return dg_flux_pallas(Sm, vm, Sp, vp, mats, axis, sign, interpret=(impl == "interpret"))
+        return ref.dg_flux_ref(tm, tp, mat, scale)
+    return dg_flux_pallas(tm, tp, mat, scale, interpret=(impl == "interpret"))
 
 
 def flash_attention_op(

@@ -3,12 +3,12 @@ kernel == ref allclose sweeps in tests/test_kernels.py)."""
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.dg.operators import riemann_correction, volume_rhs
+from repro.dg.operators import face_corrections, volume_rhs
 from repro.models.attention import naive_attention
 
 
@@ -24,17 +24,12 @@ def dg_volume_ref(
 
 
 def dg_flux_ref(
-    Sm: jnp.ndarray,  # (F, 6, M, M)
-    vm: jnp.ndarray,  # (F, 3, M, M)
-    Sp: jnp.ndarray,
-    vp: jnp.ndarray,
-    mats: jnp.ndarray,  # (F, 8): rho-,cp-,cs-,mu-,rho+,cp+,cs+,mu+
-    axis: int,
-    sign: float,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    mat_m = {"rho": mats[:, 0], "cp": mats[:, 1], "cs": mats[:, 2], "mu": mats[:, 3]}
-    mat_p = {"rho": mats[:, 4], "cp": mats[:, 5], "cs": mats[:, 6], "mu": mats[:, 7]}
-    return riemann_correction(Sm, vm, Sp, vp, axis, sign, mat_m, mat_p)
+    tm: jnp.ndarray,  # (6, 6, MM, R) minus-side traces
+    tp: jnp.ndarray,  # (6, 6, MM, R) plus side
+    mat: jnp.ndarray,  # (6, 10, R) per-face material and flag rows
+    scale: Tuple[float, float, float],
+) -> jnp.ndarray:
+    return face_corrections(tm, tp, mat, scale)
 
 
 def flash_attention_ref(

@@ -124,26 +124,23 @@ def test_activate_changes_blocks_and_results_stay_bitwise():
     q = jnp.asarray(rng.standard_normal((K, 9, M, M, M)), jnp.float32)
     ones = jnp.ones(K, jnp.float32)
     mu = jnp.zeros(K, jnp.float32)
-    Sm = jnp.asarray(rng.standard_normal((F, 6, M, M)), jnp.float32)
-    vm = jnp.asarray(rng.standard_normal((F, 3, M, M)), jnp.float32)
-    Sp = jnp.asarray(rng.standard_normal((F, 6, M, M)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((F, 3, M, M)), jnp.float32)
-    mats = jnp.asarray(np.abs(rng.standard_normal((F, 8))) + 0.5, jnp.float32)
+    tm = jnp.asarray(rng.standard_normal((6, 6, M * M, F)), jnp.float32)
+    tp = jnp.asarray(rng.standard_normal((6, 6, M * M, F)), jnp.float32)
+    mat = jnp.asarray(np.abs(rng.standard_normal((6, 10, F))) + 0.5, jnp.float32)
+    flux = lambda: np.asarray(dg_flux.dg_flux_pallas(tm, tp, mat, (1.0, 2.0, 3.0),
+                                                     interpret=True))
 
     ref_v = np.asarray(dg_volume.dg_volume_pallas(
         q, D, (2.0, 2.0, 2.0), ones, ones, mu, interpret=True))
-    ref_e, ref_f = dg_flux.dg_flux_pallas(Sm, vm, Sp, vp, mats, 0, 1.0,
-                                          interpret=True)
+    ref_f = flux()
     try:
         at.activate(_entry(be=4, bf=8))
         assert dg_volume.block_elems() == 4 and dg_flux.block_faces() == 8
         got_v = np.asarray(dg_volume.dg_volume_pallas(
             q, D, (2.0, 2.0, 2.0), ones, ones, mu, interpret=True))
-        got_e, got_f = dg_flux.dg_flux_pallas(Sm, vm, Sp, vp, mats, 0, 1.0,
-                                              interpret=True)
+        got_f = flux()
         assert (got_v == ref_v).all()
-        assert (np.asarray(got_e) == np.asarray(ref_e)).all()
-        assert (np.asarray(got_f) == np.asarray(ref_f)).all()
+        assert (got_f == ref_f).all()
     finally:
         at.activate(None)
     assert dg_volume.block_elems() == dg_volume.BE

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.dg.basis import diff_matrix, lgl_nodes_weights
+from repro.dg.operators import HAS, KEEP, riemann_correction
 from repro.kernels import ref
 from repro.kernels.dg_flux import dg_flux_pallas
 from repro.kernels.dg_volume import dg_volume_pallas
@@ -65,17 +66,23 @@ def test_dg_volume_operators_built_once_outside_the_trace():
 @pytest.mark.parametrize("dt", ["float32", "float64"])
 @pytest.mark.parametrize("axis,sign", [(0, 1.0), (1, -1.0), (2, 1.0)])
 def test_dg_flux_kernel(F, M, dt, axis, sign):
-    Sm = jnp.asarray(RNG.standard_normal((F, 6, M, M)), dt)
-    vm = jnp.asarray(RNG.standard_normal((F, 3, M, M)), dt)
-    Sp = jnp.asarray(RNG.standard_normal((F, 6, M, M)), dt)
-    vp = jnp.asarray(RNG.standard_normal((F, 3, M, M)), dt)
-    mats = np.abs(RNG.standard_normal((F, 8))) + 0.5
-    mats[: F // 3, 3] = 0.0  # acoustic minus side -> k1 = 0 branch
-    mats = jnp.asarray(mats, dt)
-    FE1, Fv1 = dg_flux_pallas(Sm, vm, Sp, vp, mats, axis, sign, interpret=True)
-    FE2, Fv2 = ref.dg_flux_ref(Sm, vm, Sp, vp, mats, axis, sign)
-    np.testing.assert_allclose(FE1, FE2, **_tol(dt))
-    np.testing.assert_allclose(Fv1, Fv2, **_tol(dt))
+    """All six faces of F element rows against the oracle; on the face of
+    (axis, sign) a third of the rows have an acoustic minus side (k1 = 0),
+    some a physical boundary (mirrored plus side), some a skip flag."""
+    tm = jnp.asarray(RNG.standard_normal((6, 6, M * M, F)), dt)
+    tp = jnp.asarray(RNG.standard_normal((6, 6, M * M, F)), dt)
+    mat = np.abs(RNG.standard_normal((6, 10, F))) + 0.5
+    f = 2 * axis + (sign > 0)
+    mat[f, 3, : F // 3] = 0.0  # acoustic minus side -> k1 = 0 branch
+    mat[f, HAS, 1::4] = 0.0  # physical boundary: the mirror
+    mat[f, KEEP, 2::5] = 0.0  # skip face: no correction
+    mat = jnp.asarray(mat, dt)
+    scale = (-2.0, -3.0, -4.0)
+    got = dg_flux_pallas(tm, tp, mat, scale, interpret=True)
+    np.testing.assert_allclose(got, ref.dg_flux_ref(tm, tp, mat, scale), **_tol(dt))
+    want_f = riemann_correction(tm[f], tp[f], mat[f], sign, scale[axis])
+    np.testing.assert_allclose(got[f], want_f, **_tol(dt))
+    assert not np.asarray(got[f][:, :, 2::5]).any()
 
 
 @pytest.mark.parametrize("S,D,blocks", [(256, 64, (64, 64)), (192, 32, (64, 32)), (128, 128, (128, 128))])

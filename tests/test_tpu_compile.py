@@ -1,20 +1,23 @@
 """The main path's Pallas programs compile for a TPU v5e, with no chip attached.
 
 Each test compiles at the ``dg-paper`` size (order 7, K = 8192, float32)
-for a described ``v5e:2x2`` topology: the volume kernel, the flux kernel,
-and the fused step-loop program of ``FusedStepPipeline`` with
-``kernel_impl="pallas"``.  Mosaic refuses here what it would refuse on the
-chip (layouts, VMEM), so these guard the kernels at no chip time.  Nothing
-runs: a pass says the programs compile and fit, not that they are right or
-fast.
+for a described ``v5e:2x2`` topology: the volume kernel, the flux kernel
+and the whole flux stage at the cells' row counts, and the fused step-loop
+program of ``FusedStepPipeline`` with ``kernel_impl="pallas"``.  Mosaic
+refuses here what it would refuse on the chip (layouts, VMEM), so these
+guard the kernels at no chip time.  Nothing runs: a pass says the programs
+compile and fit, not that they are right or fast.
 
 The topology is described inside a module fixture (not at import, not in
 ``conftest.py``): only the worker that runs this file loads the TPU
 compiler.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 K, ORDER = 8192, 7
@@ -82,14 +85,50 @@ def test_volume_kernel_compiles(one_chip):
                    *[_f32((K,), one_chip)] * 3).compile())
 
 
-@pytest.mark.parametrize("axis,sign", [(0, -1.0), (2, 1.0)])
-def test_flux_kernel_compiles(one_chip, axis, sign):
+# element rows of the flux stage in each cell: the nested envelope's gathered
+# rows, and the sharded slab with its two halo layers
+CELL_ROWS = [9728, 8704]
+
+
+@pytest.mark.parametrize("R", CELL_ROWS)
+def test_flux_kernel_compiles(one_chip, R):
     from repro.kernels.dg_flux import dg_flux_pallas
 
-    f = jax.jit(lambda Sm, vm, Sp, vp, mats: dg_flux_pallas(
-        Sm, vm, Sp, vp, mats, axis, sign, interpret=False))
-    S, v = _f32((K, 6, M, M), one_chip), _f32((K, 3, M, M), one_chip)
-    _check(f.lower(S, v, S, v, _f32((K, 8), one_chip)).compile())
+    f = jax.jit(lambda tm, tp, mat: dg_flux_pallas(
+        tm, tp, mat, (-1.0, -2.0, -3.0), interpret=False))
+    t = _f32((6, 6, M * M, R), one_chip)
+    _check(f.lower(t, t, _f32((6, 10, R), one_chip)).compile())
+
+
+def _row_arrays(text, R):
+    """(dims, minor-to-major) of every f32 array of the optimized HLO that
+    has an axis of R rows."""
+    for m in re.finditer(r"f32\[([\d,]+)\]\{([\d,]+)", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        if R in dims:
+            yield dims, [int(d) for d in m.group(2).split(",")]
+
+
+@pytest.mark.parametrize("R", CELL_ROWS)
+def test_surface_rhs_compiles_lane_dense(one_chip, R):
+    """The whole flux stage at a cell's row count: it fits, and every array
+    of the optimized program that holds face or volume data of R rows (at
+    least M*M values per row; the per-row material lines are smaller) is
+    lane-dense: its minor-most dim as laid out in memory holds at least 128
+    elements, so no (M, M) = (8, 8) face pair is tiled (8, 128)."""
+    from repro.dg.operators import surface_rhs
+
+    rng = np.random.default_rng(0)
+    nbr = jnp.asarray(rng.integers(-2, R, (R, 6)), jnp.int32)
+    lift = (32.0, 32.0, 32.0)
+    f = jax.jit(lambda q, nbr, *m: surface_rhs(q, nbr, lift, *m, kernel_impl="pallas"))
+    compiled = f.lower(_f32((R, 9, M, M, M), one_chip),
+                       jax.ShapeDtypeStruct(nbr.shape, nbr.dtype, sharding=one_chip),
+                       *[_f32((R,), one_chip)] * 5).compile()
+    _check(compiled)
+    narrow = [(d, l) for d, l in _row_arrays(compiled.as_text(), R)
+              if np.prod(d) >= R * M * M and d[l[0]] < 128]
+    assert not narrow, narrow[:5]
 
 
 def test_fused_pipeline_compiles(one_chip):
