@@ -389,9 +389,7 @@ class PartitionedDG:
                 return pipe.run(q_part, n_steps, dt=dt)
             done = 0
             while done < n_steps:
-                chunk = n_steps - done
-                if executor.rebalance_every > 0:
-                    chunk = min(executor.rebalance_every, chunk)
+                chunk = executor.next_chunk(n_steps - done)
                 q_part, report = pipe.run_observed(q_part, chunk, dt=dt)
                 executor.observe_chunk(report, chunk)
                 done += chunk
@@ -408,8 +406,8 @@ class PartitionedDG:
         done = 0
         while done < n_steps:
             chunk = n_steps - done
-            if executor is not None and executor.rebalance_every > 0:
-                chunk = min(executor.rebalance_every, chunk)
+            if executor is not None:
+                chunk = executor.next_chunk(chunk)
             t0 = time.perf_counter()
             for _ in range(chunk):
                 q_part, res = self._step_jit(q_part, res, dt_j)

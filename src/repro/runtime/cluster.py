@@ -259,8 +259,8 @@ class SimulatedCluster:
             done = 0
             while done < n_steps:
                 chunk = n_steps - done
-                if observe and self.executor.rebalance_every > 0:
-                    chunk = min(self.executor.rebalance_every, chunk)
+                if observe:
+                    chunk = self.executor.next_chunk(chunk)
                 if self.injector is not None:
                     # probe every node's dispatch at the global step this
                     # chunk starts from (the executor's step counter —

@@ -311,6 +311,11 @@ class NestedPartitionExecutor:
         self.offsets: Optional[np.ndarray] = None
         self._resplice_hooks: List[Callable[[], None]] = []
         self.history: List[Plan] = []
+        # calibration-loop rounds run, and those whose solved counts differ
+        # from the split before them (a converged split rebalances without
+        # changing the plan)
+        self.rebalances = 0
+        self.plans_changed = 0
 
         w0 = np.asarray(
             initial_weights if initial_weights is not None else np.ones(n_partitions),
@@ -549,7 +554,10 @@ class NestedPartitionExecutor:
         )
         self.round += 1
         plan = dataclasses.replace(self.solve(w), round=self.round)
+        changed = not np.array_equal(plan.counts, self.counts)
         self.apply(plan)
+        self.rebalances += 1
+        self.plans_changed += int(changed)
         return plan
 
     def plan_from_report(
@@ -655,6 +663,15 @@ class NestedPartitionExecutor:
         if step % self.rebalance_every:
             return None
         return self.rebalance()
+
+    def next_chunk(self, remaining: int) -> int:
+        """Steps a chunked step driver runs before its next observation:
+        ``remaining``, cut so the chunk ends on the next rebalance boundary
+        whatever step the run starts from (all of it with the schedule off)."""
+        every = self.rebalance_every
+        if every <= 0:
+            return int(remaining)
+        return min(int(remaining), every - self._step % every)
 
     def advance(self, n_steps: int = 1) -> Optional[Plan]:
         """Advance the step counter by ``n_steps`` and rebalance if the
@@ -998,9 +1015,7 @@ class BlockedDGEngine:
         if fused:
             done = 0
             while done < n_steps:
-                chunk = n_steps - done
-                if self.executor.rebalance_every > 0:
-                    chunk = min(self.executor.rebalance_every, chunk)
+                chunk = self.executor.next_chunk(n_steps - done)
                 # after a resplice the pipeline rebuilds its tables; the
                 # compiled program is reused while the bucket signature
                 # (stable under bucketed counts) recurs

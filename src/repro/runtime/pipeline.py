@@ -155,6 +155,7 @@ class FusedStepPipeline:
     def _build_tables(self) -> None:
         from jax.profiler import TraceAnnotation
 
+        self.stats.table_builds += 1
         with TraceAnnotation("dg.tables"):
             if self.layout == "envelope":
                 self._build_tables_envelope()
@@ -429,6 +430,7 @@ class FusedStepPipeline:
                 # fori_loop with a TRACED trip count: one compiled program
                 # per bucket signature serves every horizon (a per-n cache
                 # would recompile and retain a program per distinct n)
+                self.stats.programs_built += 1  # at trace time only
                 def body(_, carry):
                     q, res = carry
                     return lsrk45_step(q, res, lambda x: rhs(x, tables, base), dt)
@@ -458,6 +460,7 @@ class FusedStepPipeline:
                 # on-device per-step observation slots into, and a cluster
                 # pipeline only ever compiles THIS family (run(price=...)
                 # every call), so no program is compiled twice in practice.
+                self.stats.programs_built += 1  # at trace time only
                 def body(_, carry):
                     q, res, acc = carry
                     q, res = lsrk45_step(q, res, lambda x: rhs(x, tables, base), dt)

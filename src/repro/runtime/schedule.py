@@ -57,6 +57,12 @@ class DispatchStats:
     # ``dispatches`` this pins the observe-path invariant — observe=True
     # costs exactly ONE dispatch per rebalance chunk, never one per step
     observe_chunks: int = 0
+    # stacked-table builds (one per resplice that a later dispatch sees)
+    # and step-loop programs traced, plain and priced: a resplice that keeps
+    # the bucket signature rebuilds tables and traces nothing.  Counted by
+    # ``FusedStepPipeline``, whose programs are keyed on that signature
+    table_builds: int = 0
+    programs_built: int = 0
     # per-kernel launch sites per RHS evaluation inside the most recently
     # used compiled program, recorded at TRACE time (the stage scan traces
     # its body once, so launch sites per rhs = launches per stage = launches
